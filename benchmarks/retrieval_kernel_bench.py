@@ -46,6 +46,7 @@ from repro.core.mapping import GamConfig, sparse_map
 from repro.core.retrieval import masked_topk
 from repro.kernels.gam_score import NEG
 from repro.kernels.ops import gam_retrieve
+from repro.launch.compile_cache import enable_compile_cache
 from repro.retriever import RetrieverSpec, open_retriever
 
 
@@ -308,4 +309,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

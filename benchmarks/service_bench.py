@@ -59,6 +59,7 @@ import time
 import numpy as np
 
 from repro.core.mapping import GamConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.retriever import Retriever, RetrieverSpec, open_retriever
 
 
@@ -756,7 +757,7 @@ def _multihost_worker(args) -> None:
         print("MULTIHOST_RESULT " + json.dumps(res), flush=True)
 
 
-def _spawn_multihost(args) -> dict | None:
+def _spawn_multihost(args) -> dict:
     from repro.launch.procs import free_coordinator, run_workers
 
     base = [sys.executable, os.path.abspath(__file__),
@@ -770,22 +771,20 @@ def _spawn_multihost(args) -> dict | None:
         [base + ["--worker-id", str(i)]
          for i in range(args.multihost_procs)], capture=True)
     if any(codes):
-        return None
+        raise RuntimeError(f"multihost worker exit codes {codes}")
     for out in outs:
         for line in out.splitlines():
             if line.startswith("MULTIHOST_RESULT "):
                 return json.loads(line[len("MULTIHOST_RESULT "):])
-    return None
+    raise RuntimeError("no multihost worker reported a result")
 
 
 def run_multihost_scenario(args) -> dict:
-    out = None
+    # a failed spawn fails the run: it is never replaced by a measurement
+    # of something else
     if args.multihost_procs > 1:
         out = _spawn_multihost(args)
-        if out is None:
-            print("multihost: worker spawn failed — measuring the "
-                  "in-process placement instead")
-    if out is None:
+    else:
         out = _multihost_measure(args, distributed=False)
     print(f"multihost ({out['mode']}, {out['n_hosts']} hosts): "
           f"p99={out['p99_ms']:.2f}ms, after failover "
@@ -879,4 +878,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -26,8 +26,11 @@ over item blocks so HBM output shrinks to O(Q * kappa):
     (score, global row) pairs lives in the revisited output block (VMEM
     resident across the item-block grid axis).  The merge implements the
     total order (score desc, row asc) — exactly ``lax.top_k``'s tie-break
-    over the full masked score row — so results are bit-identical to the
-    dense ``masked_topk`` path after empty-slot normalisation.
+    over the full masked score row — so the ids equal the dense
+    ``masked_topk`` path's after empty-slot normalisation, and the scores
+    equal it up to float summation order (a dot over a (bq, bn) tile and
+    one over the whole (Q, N) product may accumulate in different orders,
+    on XLA's CPU backend as on the MXU).
 
 Grid: (Q / bq, N / bn) with the item axis innermost; queries, query bits and
 the accumulator stay resident in VMEM while item blocks stream through.
@@ -304,10 +307,10 @@ def _merge_topk(acc_s, acc_r, tile_s, tile_r, *, kappa, loop_merge):
 
 
 def _kernel(skip_ref, u_ref, qb_ref, v_ref, *rest,
-            kappa, min_overlap, bn, words, loop_merge, fused_words,
+            kappa, min_overlap, bn, n_blocks, words, loop_merge, fused_words,
             quantized=False):
     if quantized:
-        # int8 factor tile + its per-block SMEM scale precede the bit refs
+        # the int8 factor tile's per-block scales (SMEM) precede the bit refs
         sc_ref, ib_ref, sp_ref, al_ref, vals_ref, rows_ref, cnt_ref = rest
     else:
         sc_ref = None
@@ -323,7 +326,7 @@ def _kernel(skip_ref, u_ref, qb_ref, v_ref, *rest,
 
     cnt_ref[...] = jnp.zeros((bq, 1), jnp.int32)
 
-    @pl.when(skip_ref[0, 0] == 0)
+    @pl.when(skip_ref[pl.program_id(0) * n_blocks + j] == 0)
     def _tile():
         ov = _overlap(qb_ref[...], ib_ref[...], words=words,
                       fused_words=fused_words)
@@ -331,11 +334,12 @@ def _kernel(skip_ref, u_ref, qb_ref, v_ref, *rest,
         cnt_ref[...] = jnp.sum(cand.astype(jnp.int32), axis=1, keepdims=True)
         v = v_ref[...]
         if quantized:
-            # in-loop decode: int8 tile * per-block scale (one SMEM scalar)
-            v = v.astype(jnp.float32) * sc_ref[0, 0]
+            # in-loop decode: int8 tile * its block's scale (one SMEM scalar)
+            v = v.astype(jnp.float32) * sc_ref[j]
         scores = jax.lax.dot_general(
             u_ref[...], v,
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
         col = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
@@ -357,9 +361,23 @@ class GamRetrieveResult(NamedTuple):
 @partial(jax.jit, static_argnames=("kappa", "min_overlap", "bq", "bn",
                                    "words", "n_pad", "interpret",
                                    "loop_merge"))
-def _gam_retrieve(users, factors, q_tau, q_mask, alive, ibT, union, bspill,
-                  spill8, *, kappa, min_overlap, bq, bn, words, n_pad,
+def _gam_retrieve(users, factors, scales, q_tau, q_mask, alive, ibT, union,
+                  bspill, spill8, *, kappa, min_overlap, bq, bn, words, n_pad,
                   interpret, loop_merge):
+    """One fused launch.  ``scales`` is None on the f32 path; on the int8
+    path ``factors`` is the quantized (n_pad, k) slab, ``scales`` its
+    (1, n_blocks) per-block dequant scales, and ``kappa`` the rerank POOL
+    width (the caller re-ranks the pool against exact f32 rows).
+
+    Tiling for Mosaic: the skip map and the scales are whole 1-D SMEM
+    arrays indexed by grid position (a (1, 1) block of a 2-D array breaks
+    the (8, 128) block rule), and the per-block candidate counts come out
+    as (n_blocks, Q, 1) so each grid step writes one (bq, 1) column block.
+    The skip map takes 4 bytes per (query block, item block) tile of a
+    v5e's 1 MiB SMEM: Q=256 over 2^22 rows at bn=256 compiles, Q=1024 is
+    refused.
+    """
+    quantized = scales is not None
     q, k = users.shape
     bq = effective_bq(q, bq)
     qp = -(-q // bq) * bq
@@ -377,20 +395,27 @@ def _gam_retrieve(users, factors, q_tau, q_mask, alive, ibT, union, bspill,
 
     up = jnp.pad(users.astype(jnp.float32), ((0, qp - q), (0, 0)))
     qbp = jnp.pad(q_bits, ((0, qp - q), (0, 0)))
-    fp = jnp.pad(factors.astype(jnp.float32),
-                 ((0, n_pad - factors.shape[0]), (0, 0)))
     al8 = jnp.pad(alive.astype(jnp.int8), (0, n_pad - alive.shape[0]))[None, :]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    if quantized:
+        fp, extra, extra_specs = factors, [scales.reshape(-1)], [smem]
+    else:
+        fp = factors.astype(jnp.float32)
+        if fp.shape[0] != n_pad:      # a zero-width pad would still copy
+            fp = jnp.pad(fp, ((0, n_pad - fp.shape[0]), (0, 0)))
+        extra, extra_specs = [], []
 
     vals, rows, cnt = pl.pallas_call(
         partial(_kernel, kappa=kappa, min_overlap=min_overlap, bn=bn,
-                words=words, loop_merge=loop_merge, fused_words=interpret),
+                n_blocks=nb, words=words, loop_merge=loop_merge,
+                fused_words=interpret, quantized=quantized),
         grid=(qp // bq, nb),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, j),
-                         memory_space=pltpu.SMEM),
+            smem,
             pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, words), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, k), lambda i, j: (j, 0)),
+            *extra_specs,
             pl.BlockSpec((words, bn), lambda i, j: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
@@ -398,82 +423,19 @@ def _gam_retrieve(users, factors, q_tau, q_mask, alive, ibT, union, bspill,
         out_specs=(
             pl.BlockSpec((bq, kappa), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, kappa), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((pl.squeezed, bq, 1), lambda i, j: (j, i, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((qp, kappa), jnp.float32),
             jax.ShapeDtypeStruct((qp, kappa), jnp.int32),
-            jax.ShapeDtypeStruct((qp, nb), jnp.int32),
+            jax.ShapeDtypeStruct((nb, qp, 1), jnp.int32),
         ),
         interpret=interpret,
-    )(skip, up, qbp, fp, ibT, spill8, al8)
+    )(skip.reshape(-1), up, qbp, fp, *extra, ibT, spill8, al8)
 
     vals = vals[:q]
     rows = jnp.where(vals <= NEG / 2, -1, rows[:q])
-    return GamRetrieveResult(vals, rows, cnt[:q], skip == 1)
-
-
-@partial(jax.jit, static_argnames=("kappa", "min_overlap", "bq", "bn",
-                                   "words", "n_pad", "interpret",
-                                   "loop_merge"))
-def _gam_retrieve_q(users, factors_q, scales, q_tau, q_mask, alive, ibT,
-                    union, bspill, spill8, *, kappa, min_overlap, bq, bn,
-                    words, n_pad, interpret, loop_merge):
-    """The int8 variant of :func:`_gam_retrieve`: streams the quantized
-    (n_pad, k) slab plus a (1, n_blocks) scale row and decodes per tile
-    inside the kernel.  ``kappa`` here is the rerank POOL width — the
-    caller re-ranks the pool against exact f32 rows afterwards."""
-    q, k = users.shape
-    bq = effective_bq(q, bq)
-    qp = -(-q // bq) * bq
-    nb = n_pad // bn
-
-    q_bits = _pack_patterns_jnp(q_tau, q_mask, words)
-
-    ub = jnp.sum(jax.lax.population_count(
-        q_bits[:, None, :] & union[None, :, :]).astype(jnp.int32), axis=-1)
-    possible = (ub >= min_overlap) | bspill[None, :]            # (q, nb)
-    possible = jnp.pad(possible, ((0, qp - q), (0, 0)))
-    skip = jnp.logical_not(
-        possible.reshape(qp // bq, bq, nb).any(axis=1)).astype(jnp.int32)
-
-    up = jnp.pad(users.astype(jnp.float32), ((0, qp - q), (0, 0)))
-    qbp = jnp.pad(q_bits, ((0, qp - q), (0, 0)))
-    al8 = jnp.pad(alive.astype(jnp.int8), (0, n_pad - alive.shape[0]))[None, :]
-
-    vals, rows, cnt = pl.pallas_call(
-        partial(_kernel, kappa=kappa, min_overlap=min_overlap, bn=bn,
-                words=words, loop_merge=loop_merge, fused_words=interpret,
-                quantized=True),
-        grid=(qp // bq, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, j),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, words), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, j),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((words, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-        ],
-        out_specs=(
-            pl.BlockSpec((bq, kappa), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, kappa), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, 1), lambda i, j: (i, j)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((qp, kappa), jnp.float32),
-            jax.ShapeDtypeStruct((qp, kappa), jnp.int32),
-            jax.ShapeDtypeStruct((qp, nb), jnp.int32),
-        ),
-        interpret=interpret,
-    )(skip, up, qbp, factors_q, scales, ibT, spill8, al8)
-
-    vals = vals[:q]
-    rows = jnp.where(vals <= NEG / 2, -1, rows[:q])
-    return GamRetrieveResult(vals, rows, cnt[:q], skip == 1)
+    return GamRetrieveResult(vals, rows, cnt[:, :q, 0].T, skip == 1)
 
 
 def _rerank_pool(pool_res: GamRetrieveResult, users, factors,
@@ -488,7 +450,10 @@ def _rerank_pool(pool_res: GamRetrieveResult, users, factors,
     dense oracle."""
     rows_p = np.asarray(pool_res.rows)
     vals_p = np.asarray(pool_res.vals, np.float32)
-    fr = np.asarray(factors, np.float32)
+    # only the pool's rows leave the device, never the whole f32 slab
+    pool_f = np.asarray(jnp.take(jnp.asarray(factors),
+                                 jnp.asarray(np.maximum(rows_p, 0)), axis=0),
+                        np.float32)
     un = np.asarray(users, np.float32)
     qn = un.shape[0]
     out_s = np.full((qn, kappa), NEG, np.float32)
@@ -497,9 +462,8 @@ def _rerank_pool(pool_res: GamRetrieveResult, users, factors,
     for qi in range(qn):
         valid = (rows_p[qi] >= 0) & (vals_p[qi] > NEG / 2)
         ex = np.full(rows_p.shape[1], NEG, np.float32)
-        vr = rows_p[qi][valid].astype(np.int64)
-        if vr.size:
-            ex[valid] = fr[vr] @ un[qi]
+        if valid.any():
+            ex[valid] = pool_f[qi][valid] @ un[qi]
         key_rows = np.where(valid, rows_p[qi].astype(np.int64), empty_key)
         order = np.lexsort((key_rows, -ex))[:kappa]
         out_s[qi] = ex[order]
@@ -530,6 +494,11 @@ def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,
     rerank_factor`` pool, which is then re-ranked against the exact f32
     ``factors`` rows — ``factors`` becomes the exact re-rank store and is
     never shipped through the kernel launch.
+
+    Scores come from an f32 ``dot_general`` at ``Precision.HIGHEST`` (full
+    f32 passes on the MXU, not one bf16 pass).  Invariant against the dense
+    oracle: the same ids under (score desc, row asc); scores equal up to
+    float summation order.
     """
     factors = jnp.asarray(factors)
     if factors.shape[0] != meta.n_rows:
@@ -543,7 +512,7 @@ def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,
         kappa = int(kappa)
         pool = max(kappa, min(kappa * max(1, int(rerank_factor)),
                               meta.n_pad))
-        pool_res = _gam_retrieve_q(
+        pool_res = _gam_retrieve(
             jnp.asarray(users), meta.factors_q, meta.scales,
             jnp.asarray(q_tau), jnp.asarray(q_mask, bool),
             jnp.asarray(alive), meta.item_bits_t, meta.block_union,
@@ -553,7 +522,7 @@ def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,
             interpret=bool(interpret), loop_merge=bool(loop_merge))
         return _rerank_pool(pool_res, users, factors, kappa)
     return _gam_retrieve(
-        jnp.asarray(users), factors, jnp.asarray(q_tau),
+        jnp.asarray(users), factors, None, jnp.asarray(q_tau),
         jnp.asarray(q_mask, bool), jnp.asarray(alive), meta.item_bits_t,
         meta.block_union, meta.block_spill, meta.spill8,
         kappa=int(kappa), min_overlap=int(min_overlap), bq=int(bq),

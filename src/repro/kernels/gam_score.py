@@ -33,6 +33,7 @@ def _kernel(u_ref, v_ref, m_ref, o_ref):
     scores = jax.lax.dot_general(
         u_ref[...], v_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     o_ref[...] = jnp.where(m_ref[...] != 0, scores, NEG)

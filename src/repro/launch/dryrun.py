@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
 """Multi-pod dry-run: lower + compile every (arch x input-shape) combination
 on the production mesh, print memory/cost analyses, and dump the roofline
 inputs to a JSON ledger.
@@ -14,6 +11,7 @@ per-device memory fits; failures here are bugs in the system.
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -24,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 from repro.launch.steps import (
     abstract_cache,
     abstract_opt_state,
@@ -54,17 +52,10 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalised across jax versions.
-
-    Older jaxlibs return one properties dict per device program (a list);
-    newer ones return the dict directly.  Every consumer (dry-run ledger,
-    perf probe, roofline, tests) reads through here so the jax pin can move
-    without breaking the launchers again.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a plain dict (empty when the backend
+    reports nothing) — the one reader the dry-run ledger, perf probe,
+    roofline and tests share."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def collective_bytes(hlo_text: str) -> dict:
@@ -141,10 +132,10 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     mem = compiled.memory_analysis()
     cost = cost_analysis_dict(compiled)
     rec["bytes_per_device"] = {
-        "argument": getattr(mem, "argument_size_in_bytes", None),
-        "output": getattr(mem, "output_size_in_bytes", None),
-        "temp": getattr(mem, "temp_size_in_bytes", None),
-        "peak": getattr(mem, "peak_memory_in_bytes", None),
+        "argument": mem.argument_size_in_bytes,
+        "output": mem.output_size_in_bytes,
+        "temp": mem.temp_size_in_bytes,
+        "peak": mem.peak_memory_in_bytes,
     }
     rec["flops_per_device"] = cost.get("flops", 0.0)
     rec["hbm_bytes_per_device"] = (cost.get("bytes accessed", 0.0))
@@ -161,6 +152,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main() -> None:
+    force_host_devices(512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=tuple(SHAPES))

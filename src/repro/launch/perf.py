@@ -1,5 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 """Perf hillclimbing driver (§Perf): lower+compile named optimization
 variants for a (arch, shape) pair and report the three roofline terms for
 each, so the hypothesis -> change -> measure loop is fully scripted.
@@ -22,6 +20,7 @@ Usage:
 """
 import argparse
 import json
+import os
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -31,7 +30,7 @@ from repro.configs.registry import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES
 from repro.launch.dryrun import (build_lowered, collective_bytes,
                                  cost_analysis_dict)
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 from repro.launch.roofline import (
     HBM_BW, ICI_BW, PEAK_FLOPS, _with_layers, model_flops)
 from repro.launch.steps import (
@@ -111,8 +110,8 @@ def _probe(cfg, shape, mesh, *, gam_head=False):
     return {"flops": cost.get("flops", 0.0),
             "bytes": cost.get("bytes accessed", 0.0),
             "coll": sum(coll.values()),
-            "peak": getattr(mem, "peak_memory_in_bytes", None),
-            "arg": getattr(mem, "argument_size_in_bytes", None)}
+            "peak": mem.peak_memory_in_bytes,
+            "arg": mem.argument_size_in_bytes}
 
 
 def measure(arch: str, shape_name: str, variant: str, *,
@@ -121,8 +120,8 @@ def measure(arch: str, shape_name: str, variant: str, *,
     cfg, extra = apply_variant(get_config(arch), variant)
     if extra.pop("mesh1", False):
         # the paper's serving regime: single-chip (or few-chip) deployment
-        import jax as _jax
-        mesh = _jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         chips = 1
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
@@ -156,6 +155,7 @@ def measure(arch: str, shape_name: str, variant: str, *,
 
 
 def main():
+    force_host_devices(512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--shape", choices=tuple(SHAPES), required=True)

@@ -8,6 +8,11 @@ environment pinned to the CPU backend with the forced-host-device-count
 flag scrubbed (each worker owns exactly one local device), and supervision
 that cannot leak children on a hang.  This module is the single owner of
 that recipe.
+
+The recipe is a CPU demo: on a machine whose JAX backend is an accelerator
+:func:`run_workers` refuses, rather than serve from the CPU while this
+process holds the chip.  There, one process drives every local chip (the
+``sharded`` backend over ``launch.mesh.make_index_mesh``).
 """
 from __future__ import annotations
 
@@ -16,7 +21,25 @@ import socket
 import subprocess
 import time
 
-__all__ = ["free_coordinator", "run_workers", "worker_env"]
+import jax
+
+__all__ = ["free_coordinator", "require_cpu_backend", "run_workers",
+           "worker_env"]
+
+
+def require_cpu_backend(what: str) -> None:
+    """Raise ``RuntimeError`` unless this process's JAX backend is the CPU.
+
+    Spawned workers are pinned to the CPU (:func:`worker_env`); on an
+    accelerator host they would quietly serve from the CPU while this
+    process holds the chip."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what} spawns CPU worker processes (a multi-process CPU "
+            f"demo), but this machine's JAX backend is {backend!r}: one "
+            f"process drives every local chip — serve the 'sharded' "
+            f"backend over repro.launch.mesh.make_index_mesh instead")
 
 
 def free_coordinator(host: str = "127.0.0.1") -> str:
@@ -44,8 +67,10 @@ def run_workers(commands: list[list[str]], *, timeout: float = 600.0,
 
     Returns ``(exit_codes, stdouts)`` (stdouts empty unless ``capture``).
     On deadline every straggler is killed and reported as exit code 124 —
-    a hung collective never wedges the caller.
+    a hung collective never wedges the caller.  Refuses (``RuntimeError``,
+    nothing spawned) on an accelerator host: :func:`require_cpu_backend`.
     """
+    require_cpu_backend("spawning local jax.distributed workers")
     env = worker_env()
     procs = [subprocess.Popen(cmd, env=env,
                               stdout=subprocess.PIPE if capture else None,

@@ -1,5 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 """Roofline analysis from the compiled dry-run artifacts (TPU v5e target).
 
 Terms (per arch x shape x mesh), all derived WITHOUT hardware:
@@ -20,13 +18,14 @@ remat/redundancy waste.
 """
 import argparse
 import json
+import os
 
 from repro.configs.base import ModelConfig
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES
 from repro.launch.dryrun import (SKIPS, build_lowered, collective_bytes,
                                  cost_analysis_dict)
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 
 PEAK_FLOPS = 197e12      # bf16 / chip
 HBM_BW = 819e9           # B/s / chip
@@ -66,9 +65,9 @@ def _costs(cfg, shape_name, mesh):
         "coll": sum(coll.values()),
         "coll_by_kind": coll,
         "mem": {
-            "argument": getattr(mem, "argument_size_in_bytes", None),
-            "temp": getattr(mem, "temp_size_in_bytes", None),
-            "peak": getattr(mem, "peak_memory_in_bytes", None),
+            "argument": mem.argument_size_in_bytes,
+            "temp": mem.temp_size_in_bytes,
+            "peak": mem.peak_memory_in_bytes,
         },
     }
 
@@ -138,6 +137,7 @@ def roofline_for(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main():
+    force_host_devices(512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=tuple(SHAPES))

@@ -12,7 +12,8 @@ Usage:
   PYTHONPATH=src python -m repro.launch.serve --service --hosts 2 \
       --replication 2 --items 2000 --shards 4 [--fail-host 1]
 
-``--hosts N`` spawns N local worker processes, joins them into one
+``--hosts N`` (a CPU demo; refused where JAX's backend is an accelerator)
+spawns N local worker processes, joins them into one
 ``jax.distributed`` mesh (gloo CPU collectives) and serves the catalog from
 the ``sharded-multihost`` backend: every worker drives the identical SPMD
 request stream, each computes only the placement slices routed to it, and
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config, get_reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving import Engine, ServeConfig
 
@@ -666,6 +668,7 @@ def main():
                          "faults disabled and require bit-identical "
                          "answers (exits 1 on any wrong answer)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if (args.learn or args.learn_events) and args.hosts > 1:
         ap.error("--learn runs on the single-host service loop "
@@ -684,6 +687,11 @@ def main():
                 ap.error(f"--fail-host {args.fail_host} out of range "
                          f"[0, {args.hosts})")
         if args.host_id is None:
+            from repro.launch.procs import require_cpu_backend
+            try:
+                require_cpu_backend("--hosts N")
+            except RuntimeError as e:
+                ap.error(str(e))
             sys.exit(_spawn_hosts(args))
         serve_retrieval_multihost(args)
         return

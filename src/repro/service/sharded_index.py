@@ -18,7 +18,10 @@ mask or score tensor is ever materialised.
 Consecutive shards sharing one ``bn`` form a *group*: one contiguous slab of
 the flat factor matrix with one ``RetrievalMeta`` and one kernel launch (the
 uniform default is a single group — exactly the legacy single launch).
-Heterogeneous partitions launch once per group and merge on host.
+Heterogeneous partitions launch once per group and merge on host.  Built
+with a ``launch.mesh.make_index_mesh`` mesh, a single group's kernel state
+is partitioned block-aligned over the devices, and the group launches once
+per device over that device's rows, through the same host merge.
 
 Merge semantics: the kernel's accumulator realises the total order
 (score desc, global row asc); live rows appear in the flat layout in id
@@ -147,8 +150,8 @@ class ShardedGamIndex:
         self.bucket = bucket
         self.mesh = mesh
         self.metas: list[RetrievalMeta] = list(metas or [])
-        # per-group device slabs (single-group: the arrays themselves, so a
-        # mesh-placed flat factor matrix keeps its sharding)
+        # per-group device slabs (with a mesh, the single group's slab is
+        # partitioned over the devices by _place_on_mesh below)
         factors = jnp.asarray(factors)
         groups = partition.groups
         if len(groups) == 1:
@@ -167,6 +170,8 @@ class ShardedGamIndex:
             self.metas = [m if m.quantize == "int8"
                           else quantize_meta(m, np.asarray(self.factors_g[g]))
                           for g, m in enumerate(self.metas)]
+        if mesh is not None and len(groups) == 1 and self.metas:
+            self._place_on_mesh(mesh)
         # flat row -> catalog id (-1 on pad rows), and id -> flat row
         self._padded_ids = np.full(partition.n_rows, -1, np.int64)
         self._row_of: dict[int, int] = {}
@@ -283,16 +288,76 @@ class ShardedGamIndex:
                 "with a uniform bn to keep item-axis sharding",
                 RuntimeWarning, stacklevel=2)
         if mesh is not None and len(partition.groups) == 1:
+            # posting tables shard-major over the items axis; the kernel
+            # state (factors, patterns, alive) is placed by the constructor
             from repro.sharding.specs import index_shardings
             arrs = {"tables": tables_j, "counts": counts_j,
-                    "spills": spills_j, "factors": factors_j}
+                    "spills": spills_j}
             arrs = jax.device_put(arrs, index_shardings(mesh, arrs))
             tables_j, counts_j = arrs["tables"], arrs["counts"]
-            spills_j, factors_j = arrs["spills"], arrs["factors"]
+            spills_j = arrs["spills"]
         return ShardedGamIndex(cfg, item_ids, tables_j, counts_j, spills_j,
                                factors_j, alive, partition, min_overlap,
                                bucket, mesh, metas, quantize=quantize,
                                rerank_factor=rerank_factor)
+
+    def _place_on_mesh(self, mesh) -> None:
+        """Partition the single group's kernel state over the mesh's items
+        axis: every device holds one contiguous, block-aligned run of rows
+        (factor rows, alive flags, packed patterns, block unions, spill
+        flags and, for int8, the slab and its scales), and :meth:`query`
+        launches the kernel once per device over that device's rows."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        meta = self.metas[0]
+        n_dev = mesh.devices.size
+        if meta.n_blocks % n_dev:
+            raise ValueError(
+                f"{meta.n_blocks} kernel blocks do not split evenly over "
+                f"{n_dev} devices; use an n_shards that is a multiple of "
+                f"the mesh size")
+
+        def put(x, axis):
+            spec = [None] * x.ndim
+            spec[axis] = "items"
+            return jax.device_put(x, NamedSharding(mesh, P(*spec)))
+
+        repl = dict(item_bits_t=put(meta.item_bits_t, 1),
+                    block_union=put(meta.block_union, 0),
+                    block_spill=put(meta.block_spill, 0),
+                    spill8=put(meta.spill8, 1))
+        if meta.quantize == "int8":
+            repl.update(factors_q=put(meta.factors_q, 0),
+                        scales=put(meta.scales, 1))
+        self.metas[0] = dataclasses.replace(meta, **repl)
+        self.factors_g[0] = put(self.factors_g[0], 0)
+        self.alive_g[0] = put(self.alive_g[0], 0)
+
+    def _launch_units(self, g: int) -> list:
+        """Kernel launches of group ``g`` as ``(row offset within the
+        group, meta, factors, alive)``: one launch for a group on one
+        device, one per device (over that device's own rows) for a group
+        partitioned over a mesh."""
+        meta, fac, alive = self.metas[g], self.factors_g[g], self.alive_g[g]
+        if len(fac.sharding.device_set) == 1:
+            return [(0, meta, fac, alive)]
+
+        def local(x):
+            return {sh.device: sh.data for sh in x.addressable_shards}
+
+        names = ["item_bits_t", "block_union", "block_spill", "spill8"]
+        if meta.quantize == "int8":
+            names += ["factors_q", "scales"]
+        parts = {n: local(getattr(meta, n)) for n in names}
+        alive_d = local(alive)
+        units = []
+        for sh in sorted(fac.addressable_shards,
+                         key=lambda sh: sh.index[0].start or 0):
+            n = sh.data.shape[0]
+            units.append((sh.index[0].start or 0, dataclasses.replace(
+                meta, n_rows=n, n_pad=n,
+                **{k: v[sh.device] for k, v in parts.items()}),
+                sh.data, alive_d[sh.device]))
+        return units
 
     # ------------------------------------------------------------- state
 
@@ -403,12 +468,13 @@ class ShardedGamIndex:
         """users (Q, k) f32 + mapped query patterns -> merged top-kappa.
 
         One fused gam_retrieve pass per bn-group (uniform partitions: exactly
-        one pass over the whole flat factor matrix): candidate pruning,
-        scoring and the in-group top-kappa merge all happen on chip
-        (zero-candidate item blocks are skipped outright); heterogeneous
-        partitions merge the per-group top-kappas on host under the same
-        (score desc, global row asc) total order, which is what keeps a
-        repartitioned catalog bit-identical to the single-launch layout.
+        one pass over the whole flat factor matrix; one per device when the
+        group is placed over a mesh): candidate pruning, scoring and the
+        in-launch top-kappa merge all happen on chip (zero-candidate item
+        blocks are skipped outright); several launches merge on host under
+        the same (score desc, global row asc) total order, which is what
+        keeps a repartitioned catalog's ids identical to the single-launch
+        layout's (scores up to float summation order).
         ``exact=True`` scores every live row through the same kernel
         (``min_overlap=0``) — the brute-force reference path.
 
@@ -423,14 +489,20 @@ class ShardedGamIndex:
         mo = 0 if exact else (self.min_overlap if min_overlap is None
                               else int(min_overlap))
         q = int(np.asarray(users).shape[0])
-        results = []
-        for g, meta in enumerate(self.metas):
-            with tracer.span("gam_retrieve", group=g, bn=meta.bn,
-                             n_rows=meta.n_rows):
-                results.append(gam_retrieve(
-                    users, self.factors_g[g], q_tau, q_mask, meta, kappa,
-                    min_overlap=mo, alive=self.alive_g[g],
-                    rerank_factor=self.rerank_factor))
+        results, offsets = [], []
+        for g in range(len(self.metas)):
+            units = self._launch_units(g)
+            for off, meta, fac, alive in units:
+                args = (users, q_tau, q_mask)
+                if len(units) > 1:       # the query goes to the rows' device
+                    args = jax.device_put(args, fac.sharding)
+                u, tq, qm = args
+                with tracer.span("gam_retrieve", group=g, bn=meta.bn,
+                                 n_rows=meta.n_rows):
+                    results.append(gam_retrieve(
+                        u, fac, tq, qm, meta, kappa, min_overlap=mo,
+                        alive=alive, rerank_factor=self.rerank_factor))
+                offsets.append(self.partition.group_rows(g)[0] + off)
         skips = (np.concatenate([expand_tile_skips(r.skipped, q)
                                  for r in results], axis=1)
                  if collect_tile_skips and results else None)
@@ -443,10 +515,9 @@ class ShardedGamIndex:
                              block_candidates=blk,
                              tiles_skipped_frac=float(res.skipped.mean()),
                              tile_skips=skips)
-        with tracer.span("group_merge", n_groups=len(results)):
-            exported = [export_topk(r.vals, r.rows,
-                                    offset=self.partition.group_rows(g)[0])
-                        for g, r in enumerate(results)]
+        with tracer.span("group_merge", n_launches=len(results)):
+            exported = [export_topk(r.vals, r.rows, offset=off)
+                        for off, r in zip(offsets, results)]
             cat_s = np.concatenate([s for s, _ in exported], axis=1)
             cat_r = np.concatenate([r for _, r in exported], axis=1)
             order = np.lexsort((cat_r, -cat_s), axis=-1)[:, :kappa]

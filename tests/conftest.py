@@ -9,6 +9,7 @@ test_gam_retrieve, ...).  They live here now: module-scope helpers
 parametrize lists and module-level constants) plus fixture spellings for
 test bodies.
 """
+import os
 import zlib
 
 import numpy as np
@@ -19,11 +20,40 @@ from repro.core.mapping import GamConfig
 # the standard mapping schema of the retrieval test suites
 CFG = GamConfig(k=16, scheme="parse_tree", threshold=0.2)
 
+#: Parity between two differently shaped computations of the same scores
+#: (a kernel tile vs the dense (Q, N) product, one shard layout vs another)
+#: holds for ids exactly and for scores up to float summation order: XLA
+#: picks each dot's accumulation order from its shape, on the CPU backend
+#: as on the MXU.  The bound is in units in the last place of 1.0, the
+#: largest term a dot of unit-norm rows sums — a score that cancels to a
+#: small value still carries the rounding of its terms.
+SCORE_MAX_ULP = 4
+
+
+def assert_scores_match(actual, desired) -> None:
+    """Scores equal up to float summation order (see SCORE_MAX_ULP)."""
+    a = np.asarray(actual, np.float32)
+    d = np.asarray(desired, np.float32)
+    assert a.shape == d.shape, (a.shape, d.shape)
+    scale = np.maximum(np.maximum(np.abs(a), np.abs(d)), np.float32(1.0))
+    off = (a != d) & ~(np.abs(a - d) <= SCORE_MAX_ULP * np.spacing(scale))
+    assert not off.any(), (
+        f"{int(off.sum())} scores differ by more than {SCORE_MAX_ULP} ulp: "
+        f"{a[off][:5]} vs {d[off][:5]}")
+
 
 def unit_factors(n: int, k: int = 16, seed: int = 0) -> np.ndarray:
     """(n, k) unit-norm float32 factor rows, deterministic in ``seed``."""
     z = np.random.default_rng(seed).normal(size=(n, k)).astype(np.float32)
     return z / np.linalg.norm(z, axis=1, keepdims=True)
+
+
+def pytest_configure(config):
+    # the mesh suites (sharding rules, dry-run wiring, index placement) need
+    # eight host devices; the count locks when JAX starts its CPU backend,
+    # so it is set here, before any test module is imported
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 
 @pytest.fixture(scope="session")

@@ -1,16 +1,17 @@
-"""Fused streaming retrieval kernel: bit-parity with the dense masked path.
+"""Fused streaming retrieval kernel: parity with the dense masked path.
 
 Every case runs in interpret mode so tier-1 stays CPU-only.  The contract
-under test: ``gam_retrieve`` returns bit-identical (ids, scores) to
-``masked_topk`` over ``DeviceIndex`` candidate masks — including score
-tie-breaks, spill-list candidates and empty-candidate padding — after the
-NEG-slot normalisation every consumer applies (fused empties are (NEG, -1);
-the dense path parks arbitrary ``lax.top_k`` indices there).
+under test: ``gam_retrieve`` returns the same ids as ``masked_topk`` over
+``DeviceIndex`` candidate masks — including score tie-breaks, spill-list
+candidates and empty-candidate padding — and the same scores up to float
+summation order, after the NEG-slot normalisation every consumer applies
+(fused empties are (NEG, -1); the dense path parks arbitrary ``lax.top_k``
+indices there).
 """
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import CFG, unit_factors as _factors
+from conftest import CFG, assert_scores_match, unit_factors as _factors
 
 from repro.core.inverted_index import DeviceIndex
 from repro.core.mapping import sparse_map
@@ -39,12 +40,12 @@ def _dense_reference(users, items, tau, mask, q_tau, q_mask, kappa, mo,
     return np.where(vals <= NEG / 2, -1, ids), vals, dev, masks
 
 
-def _assert_bit_identical(res, ref_ids, ref_vals):
+def _assert_same_topk(res, ref_ids, ref_vals):
     empty = ref_vals <= NEG / 2
     np.testing.assert_array_equal(np.asarray(res.rows), ref_ids)
     got = np.asarray(res.vals)
     np.testing.assert_array_equal(got <= NEG / 2, empty)
-    np.testing.assert_array_equal(got[~empty], ref_vals[~empty])
+    assert_scores_match(got[~empty], ref_vals[~empty])
     # fused empty slots are exactly NEG (never a fabricated score)
     assert (got[empty] == NEG).all()
 
@@ -70,7 +71,7 @@ def test_fused_bit_identical_to_masked_topk(n, q, kappa, mo, bucket, bn, bq,
     res = gam_retrieve(users, items, q_tau, q_mask, meta, kk,
                        min_overlap=mo, bq=bq, interpret=True,
                        loop_merge=loop_merge)
-    _assert_bit_identical(res, ref_ids, ref_vals)
+    _assert_same_topk(res, ref_ids, ref_vals)
     # n_scored comes from the block prepass counts and must equal the dense
     # mask's candidate count exactly
     np.testing.assert_array_equal(np.asarray(res.blk_counts).sum(1),
@@ -93,7 +94,7 @@ def test_score_ties_break_by_lowest_row():
         res = gam_retrieve(users, items, q_tau, q_mask, meta, 12,
                            min_overlap=1, bq=8, interpret=True,
                            loop_merge=loop_merge)
-        _assert_bit_identical(res, ref_ids, ref_vals)
+        _assert_same_topk(res, ref_ids, ref_vals)
 
 
 def test_all_empty_candidate_rows():
@@ -131,7 +132,7 @@ def test_block_skipping_prunes_tiles_without_changing_results():
                                 spill_rows=np.asarray(dev.spill), bn=64)
     res = gam_retrieve(users, items, q_tau, q_mask, meta, 10,
                        min_overlap=4, bq=8, interpret=True)
-    _assert_bit_identical(res, ref_ids, ref_vals)
+    _assert_same_topk(res, ref_ids, ref_vals)
     assert np.asarray(res.skipped).mean() > 0.2, "no tiles were pruned"
     # skipped tiles truly had zero candidates (skip is never lossy)
     blk = np.asarray(res.blk_counts)
@@ -152,8 +153,7 @@ def test_matches_pattern_oracle():
                                       7, min_overlap=2)
     np.testing.assert_array_equal(np.asarray(res.rows), np.asarray(rows))
     real = np.asarray(vals) > NEG / 2
-    np.testing.assert_array_equal(np.asarray(res.vals)[real],
-                                  np.asarray(vals)[real])
+    assert_scores_match(np.asarray(res.vals)[real], np.asarray(vals)[real])
 
 
 def test_pack_patterns_roundtrip():
@@ -205,7 +205,7 @@ def test_device_retriever_equals_dense_reference_end_to_end():
     vals, ids = np.asarray(vals), np.asarray(ids)
     empty = vals <= NEG / 2
     np.testing.assert_array_equal(res.ids, np.where(empty, -1, ids))
-    np.testing.assert_array_equal(res.scores[~empty], vals[~empty])
+    assert_scores_match(res.scores[~empty], vals[~empty])
     np.testing.assert_array_equal(res.n_scored, np.asarray(masks).sum(1))
 
 
@@ -232,7 +232,7 @@ def test_sharded_merge_equals_dense_reference():
     g_vals = np.asarray(got.scores)[:, :kk]
     np.testing.assert_array_equal(np.asarray(got.rows)[:, :kk], w_rows)
     real = w_vals > NEG / 2
-    np.testing.assert_array_equal(g_vals[real], w_vals[real])
+    assert_scores_match(g_vals[real], w_vals[real])
     # anything past the reference's kappa' columns is empty padding
     assert (np.asarray(got.scores)[:, kk:] <= NEG / 2).all()
     np.testing.assert_array_equal(np.asarray(got.shard_candidates),

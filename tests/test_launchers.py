@@ -1,7 +1,17 @@
 """Launcher entry points (train/serve) exercised at tiny scale."""
+import jax
 import numpy as np
+import pytest
 
 from repro.launch.train import train
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache(monkeypatch):
+    # serve.main() points JAX's persistent compile cache at the checkout for
+    # the rest of its process; in a test worker that would outlive the test
+    monkeypatch.setattr("repro.launch.serve.enable_compile_cache",
+                        lambda: None)
 
 
 def test_train_launcher_reduced_arch():
@@ -113,3 +123,51 @@ def test_serve_launcher_service_qos_flags(monkeypatch, capsys):
     serve.main()
     out = capsys.readouterr().out
     assert "qos:" in out and "upsert faults=1" in out
+
+
+def test_compile_cache_lands_in_the_checkout(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR unset the cache is the fixed
+    <checkout>/.jax_cache; when it is set, JAX reads it and nothing else is
+    configured."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = Path(__file__).resolve().parents[1]
+        assert compile_cache.enable_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", was)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_cpu_worker_spawn_refuses_on_an_accelerator(monkeypatch):
+    """--hosts N is a multi-process CPU demo: where JAX's backend is a TPU
+    it refuses before spawning anything, instead of serving from the CPU."""
+    import subprocess
+
+    from repro.launch import procs
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(subprocess, "Popen", None)    # nothing may spawn
+    with pytest.raises(RuntimeError, match="'tpu'"):
+        procs.run_workers([["true"]])
+
+
+def test_serve_hosts_refuses_on_an_accelerator(monkeypatch, capsys):
+    import sys
+
+    from repro.launch import serve
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(sys, "argv", ["serve", "--service", "--hosts", "2"])
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert exc.value.code == 2
+    assert "multi-process CPU demo" in capsys.readouterr().err

@@ -12,7 +12,7 @@ import os
 
 import numpy as np
 import pytest
-from conftest import CFG, unit_factors as _factors
+from conftest import CFG, assert_scores_match, unit_factors as _factors
 
 from repro.core.mapping import GamConfig
 from repro.retriever import (
@@ -155,7 +155,7 @@ def test_background_compact_is_part_of_the_contract(backend):
         assert steps < 100
     after = r.query(users, 10)
     np.testing.assert_array_equal(before.ids, after.ids)
-    np.testing.assert_array_equal(before.scores, after.scores)
+    assert_scores_match(before.scores, after.scores)
     if backend in ("sharded", "sharded-multihost"):
         assert steps > 0
         assert r.maintenance_stats()["generation"] == gen0 + 1
@@ -193,10 +193,10 @@ def test_pruned_mode_matches_gam_candidate_semantics(backend):
     np.testing.assert_array_equal(got.n_scored, ref.n_scored)
     np.testing.assert_allclose(got.scores, ref.scores, rtol=1e-5, atol=1e-6)
     if backend in ("sharded", "sharded-multihost"):
-        # same fused kernel as gam-device: bit-equal
+        # same fused kernel as gam-device, over another layout
         dev = open_retriever(_spec("gam-device"), items=items).query(users, 10)
         np.testing.assert_array_equal(got.ids, dev.ids)
-        np.testing.assert_array_equal(got.scores, dev.scores)
+        assert_scores_match(got.scores, dev.scores)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

@@ -1,16 +1,10 @@
 """Sharding rules + miniature-mesh integration: a scaled-down production
 mesh (4 devices in-process) trains and serves sharded without changing any
 model code — the same code path the 512-chip dry-run proves at scale."""
-import os
-
-import pytest
-
-# must run in a dedicated process: device count locks at first jax init
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.registry import get_reduced_config
@@ -29,7 +23,8 @@ pytestmark = pytest.mark.skipif(
 
 
 def small_mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_param_specs_shard_the_right_dims():
