@@ -13,7 +13,7 @@ near tie fails the run.
 ``--chips 4`` runs only the four-chip phase instead: the same service on a
 ``make_index_mesh(4)`` mesh over 2^22 items, so that each chip holds about
 what phase A's one chip holds, compared with the same index state on one
-device.
+device, for the f32 rows and for an int8 slab quantized from them.
 
 The last line of stdout is one JSON object,
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
@@ -231,6 +231,7 @@ def four_chip_phase(*, n_items: int, n_requests: int, seed: int,
     from repro.core.mapping import sparse_map
     from repro.launch.mesh import make_index_mesh
     from repro.retriever import open_retriever
+    from repro.service.sharded_index import ShardedGamIndex
 
     print(f"four-chip phase: sharded service on make_index_mesh(4), "
           f"{n_items} items x d={K}", flush=True)
@@ -253,6 +254,7 @@ def four_chip_phase(*, n_items: int, n_requests: int, seed: int,
                           for sh in sorted(arr.addressable_shards,
                                            key=lambda sh: sh.device.id))
         print(f"  {name} {tuple(arr.shape)} shards {where}", flush=True)
+    del meta          # upserts replace it; a stale copy would stay resident
     print_memory(mesh.devices.flat)
 
     t0 = time.monotonic()
@@ -267,21 +269,38 @@ def four_chip_phase(*, n_items: int, n_requests: int, seed: int,
     if served != n_requests:
         raise PhaseFailed(f"{n_requests - served} requests not served")
 
-    base = svc.base
-    one = on_one_device(base, devices[0])
     users = unit_rows(rng, N_CHECK)
     users_j = jnp.asarray(users)
     tau, vals = sparse_map(users_j, spec.cfg)
     q_mask = vals != 0.0
-    for exact in (False, True):
-        g = base.query(users_j, tau, q_mask, KAPPA, exact=exact)
-        w = one.query(users_j, tau, q_mask, KAPPA, exact=exact)
-        compare(f"{'exact' if exact else 'pruned'} path, four chips vs one",
-                users, base.rows_to_ids(g.rows, g.scores), g.scores,
-                base.rows_to_ids(w.rows, w.scores), w.scores, svc.catalog)
-        if not np.array_equal(g.shard_candidates, w.shard_candidates):
-            raise PhaseFailed("candidate counts differ between the layouts")
-    print_memory(mesh.devices.flat)
+    # the served f32 index, then the int8 slab quantized from its rows
+    base = svc.base
+    for label in ("f32", "int8"):
+        if label == "int8":
+            base = ShardedGamIndex(
+                base.cfg, base.item_ids, base.tables, base.counts,
+                base.spills, base.factors_g[0], np.asarray(base.alive_g[0]),
+                base.partition, base.min_overlap, base.bucket, mesh,
+                list(base.metas), quantize="int8",
+                rerank_factor=base.rerank_factor)
+            q8 = base.metas[0].factors_q
+            print(f"  int8 factors_q {tuple(q8.shape)} on devices "
+                  f"{sorted(d.id for d in q8.sharding.device_set)}",
+                  flush=True)
+        one = on_one_device(base, devices[0])
+        for exact in (False, True):
+            g = base.query(users_j, tau, q_mask, KAPPA, exact=exact)
+            w = one.query(users_j, tau, q_mask, KAPPA, exact=exact)
+            compare(f"{label} {'exact' if exact else 'pruned'} path, four "
+                    f"chips vs one", users,
+                    base.rows_to_ids(g.rows, g.scores), g.scores,
+                    base.rows_to_ids(w.rows, w.scores), w.scores, svc.catalog)
+            if not np.array_equal(g.shard_candidates, w.shard_candidates):
+                raise PhaseFailed("candidate counts differ between the "
+                                  "layouts")
+        print_memory(mesh.devices.flat)
+        del one, g, w
+        gc.collect()
 
 
 def main(argv=None) -> int:

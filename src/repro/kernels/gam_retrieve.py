@@ -61,9 +61,9 @@ from repro.compress.quantize import quantize_int8
 from repro.kernels.gam_score import NEG
 
 __all__ = ["RetrievalMeta", "GamRetrieveResult", "RowCapacityError",
-           "TOPK_EMPTY_ROW", "build_retrieval_meta", "effective_bq",
-           "expand_tile_skips", "export_topk", "gam_retrieve",
-           "pack_patterns"]
+           "SKIP_MAP_TILES", "TOPK_EMPTY_ROW", "build_retrieval_meta",
+           "effective_bq", "expand_tile_skips", "export_topk", "gam_retrieve",
+           "pack_patterns", "rerank_pool"]
 
 # Row sentinel for non-candidate tile entries: larger than any real global row
 # (catalogs < 2^30 rows — enforced by RowCapacityError at build/assembly
@@ -93,6 +93,12 @@ class RowCapacityError(ValueError):
 # under the (score desc, row asc) total order while staying collective-safe
 # (int32 survives cross-host all-gathers that would truncate an int64 pad).
 TOPK_EMPTY_ROW = np.int32(np.iinfo(np.int32).max)
+
+#: Most (query block, item block) tiles one launch's skip map may hold: the
+#: map sits whole in SMEM at 4 bytes a tile, and a v5e has 1 MiB of it.
+#: 2^17 tiles is Q=256 over 2^22 rows at bn=256, which compiles for a v5e
+#: (tests/test_tpu_compile.py); Q=512 there runs out of SMEM.
+SKIP_MAP_TILES = 1 << 17
 
 
 def effective_bq(q: int, bq: int = 32) -> int:
@@ -373,9 +379,9 @@ def _gam_retrieve(users, factors, scales, q_tau, q_mask, alive, ibT, union,
     arrays indexed by grid position (a (1, 1) block of a 2-D array breaks
     the (8, 128) block rule), and the per-block candidate counts come out
     as (n_blocks, Q, 1) so each grid step writes one (bq, 1) column block.
-    The skip map takes 4 bytes per (query block, item block) tile of a
-    v5e's 1 MiB SMEM: Q=256 over 2^22 rows at bn=256 compiles, Q=1024 is
-    refused.
+    The skip map takes 4 bytes per (query block, item block) tile;
+    :func:`gam_retrieve` splits a batch so it stays within
+    :data:`SKIP_MAP_TILES`.
     """
     quantized = scales is not None
     q, k = users.shape
@@ -438,8 +444,8 @@ def _gam_retrieve(users, factors, scales, q_tau, q_mask, alive, ibT, union,
     return GamRetrieveResult(vals, rows, cnt[:, :q, 0].T, skip == 1)
 
 
-def _rerank_pool(pool_res: GamRetrieveResult, users, factors,
-                 kappa: int) -> GamRetrieveResult:
+def rerank_pool(pool_res: GamRetrieveResult, users, factors,
+                kappa: int) -> GamRetrieveResult:
     """Exact f32 re-rank of a quantized-score candidate pool.
 
     For every query the pool's surviving rows are re-scored against the
@@ -478,7 +484,8 @@ def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,
                  min_overlap: int = 1, alive: jax.Array | None = None,
                  bq: int = 32, interpret: bool = False,
                  loop_merge: bool | None = None,
-                 rerank_factor: int = 4) -> GamRetrieveResult:
+                 rerank_factor: int = 4,
+                 rerank: bool = True) -> GamRetrieveResult:
     """Fused candidate-pruned top-kappa MIPS over ``meta.n_rows`` items.
 
     ``users``: (Q, k) f32 query factors; ``factors``: (n_rows, k) f32 item
@@ -493,7 +500,13 @@ def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,
     (decoded in-loop from per-block scales) and keeps a top-``kappa *
     rerank_factor`` pool, which is then re-ranked against the exact f32
     ``factors`` rows — ``factors`` becomes the exact re-rank store and is
-    never shipped through the kernel launch.
+    never shipped through the kernel launch.  With ``rerank=False`` the
+    pool comes back as launched (quantized scores, ``kappa *
+    rerank_factor`` wide), so a caller with several launches can queue
+    them all before :func:`rerank_pool` waits on each.
+
+    A batch whose skip map would pass :data:`SKIP_MAP_TILES` runs as
+    several launches over consecutive query rows.
 
     Scores come from an f32 ``dot_general`` at ``Precision.HIGHEST`` (full
     f32 passes on the MXU, not one bf16 pass).  Invariant against the dense
@@ -508,6 +521,16 @@ def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,
         alive = jnp.ones((meta.n_rows,), bool)
     if loop_merge is None:
         loop_merge = not interpret
+    q = int(np.shape(users)[0])
+    step = max(1, SKIP_MAP_TILES // meta.n_blocks) * effective_bq(q, bq)
+    if q > step:
+        parts = [gam_retrieve(
+            users[i:i + step], factors, q_tau[i:i + step],
+            q_mask[i:i + step], meta, kappa, min_overlap=min_overlap,
+            alive=alive, bq=bq, interpret=interpret, loop_merge=loop_merge,
+            rerank_factor=rerank_factor, rerank=rerank)
+            for i in range(0, q, step)]
+        return GamRetrieveResult(*(jnp.concatenate(xs) for xs in zip(*parts)))
     if meta.quantize == "int8":
         kappa = int(kappa)
         pool = max(kappa, min(kappa * max(1, int(rerank_factor)),
@@ -520,7 +543,8 @@ def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,
             kappa=pool, min_overlap=int(min_overlap), bq=int(bq),
             bn=meta.bn, words=meta.words, n_pad=meta.n_pad,
             interpret=bool(interpret), loop_merge=bool(loop_merge))
-        return _rerank_pool(pool_res, users, factors, kappa)
+        return (rerank_pool(pool_res, users, factors, kappa) if rerank
+                else pool_res)
     return _gam_retrieve(
         jnp.asarray(users), factors, None, jnp.asarray(q_tau),
         jnp.asarray(q_mask, bool), jnp.asarray(alive), meta.item_bits_t,

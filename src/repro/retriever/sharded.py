@@ -875,5 +875,7 @@ class ShardedRetriever(Retriever):
         self._bump_cache()
         self._planner = None
         # a restored skew-aware layout keeps re-planning on later compactions
-        self._rebalanced = part != Partition.uniform(part.n, part.n_shards)
+        # (a uniform cut padded for a mesh is still the uniform cut)
+        uni = Partition.uniform(part.n, part.n_shards)
+        self._rebalanced = (part.lengths, part.bns) != (uni.lengths, uni.bns)
         return self

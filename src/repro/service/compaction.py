@@ -102,6 +102,9 @@ class CompactionPlanner:
         if self.partition.n != self.n:
             raise ValueError(f"partition covers {self.partition.n} rows, "
                              f"frozen catalog has {self.n}")
+        if mesh is not None:   # block-aligned over the devices, as in build
+            self.partition = self.partition.padded_to_blocks(
+                mesh.devices.size)
         self.bucket = bucket
         self.min_overlap = min_overlap
         self.quantize = quantize

@@ -132,6 +132,20 @@ class Partition:
         lo = self.offsets[s_lo]
         return lo, lo + sum(self.caps[s_lo:s_hi])
 
+    def padded_to_blocks(self, multiple: int) -> "Partition":
+        """This layout with dead blocks added to the last shard's cap until
+        a single-group slab holds a whole multiple of ``multiple`` kernel
+        blocks, so it splits block-aligned over that many devices.  Pad
+        rows are the same dead rows every cap already ends with.  A layout
+        of several groups comes back unchanged (it is not mesh-placed)."""
+        if len(self.groups) != 1:
+            return self
+        short = -(self.n_rows // self.bns[-1]) % int(multiple)
+        if not short:
+            return self
+        return dataclasses.replace(
+            self, caps=self.caps[:-1] + (self.caps[-1] + short * self.bns[-1],))
+
     @staticmethod
     def uniform(n: int, n_shards: int) -> "Partition":
         """The legacy equal-cut layout: one shared cap and bn, pads only at

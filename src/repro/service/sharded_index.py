@@ -20,8 +20,10 @@ the flat factor matrix with one ``RetrievalMeta`` and one kernel launch (the
 uniform default is a single group — exactly the legacy single launch).
 Heterogeneous partitions launch once per group and merge on host.  Built
 with a ``launch.mesh.make_index_mesh`` mesh, a single group's kernel state
-is partitioned block-aligned over the devices, and the group launches once
-per device over that device's rows, through the same host merge.
+is partitioned block-aligned over the devices (the build pads the last
+shard with dead blocks to a whole number per device), and the group
+launches once per device over that device's rows, through the same host
+merge.
 
 Merge semantics: the kernel's accumulator realises the total order
 (score desc, global row asc); live rows appear in the flat layout in id
@@ -39,6 +41,7 @@ slice at a time; ``ShardedGamIndex.build`` runs the same stages eagerly.
 from __future__ import annotations
 
 import dataclasses
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +52,7 @@ from repro.core.mapping import GamConfig, sparse_map
 from repro.core.retrieval import masked_topk
 from repro.kernels.gam_retrieve import (RetrievalMeta, expand_tile_skips,
                                         export_topk, pack_patterns,
-                                        quantize_meta)
+                                        quantize_meta, rerank_pool)
 from repro.kernels.gam_score import NEG
 from repro.kernels.ops import gam_retrieve
 from repro.obs.tracing import NOOP_TRACER
@@ -226,6 +229,8 @@ class ShardedGamIndex:
         elif partition.n != n:
             raise ValueError(f"partition covers {partition.n} rows, "
                              f"catalog has {n}")
+        if mesh is not None:
+            partition = partition.padded_to_blocks(mesh.devices.size)
 
         if premapped is None:
             tau, vals = sparse_map(jnp.asarray(factors), cfg)
@@ -281,7 +286,6 @@ class ShardedGamIndex:
             # index_shardings partitions the single flat layout only — a
             # heterogeneous rebalance on a mesh deployment would otherwise
             # silently drop the item-axis placement, so say it out loud
-            import warnings
             warnings.warn(
                 "heterogeneous partition (multiple bn-groups) is not "
                 "mesh-partitioned yet; serving from local devices — plan "
@@ -311,10 +315,14 @@ class ShardedGamIndex:
         meta = self.metas[0]
         n_dev = mesh.devices.size
         if meta.n_blocks % n_dev:
-            raise ValueError(
+            # build() and the compaction planner pad the partition to whole
+            # blocks per device; only a hand-assembled layout gets here
+            warnings.warn(
                 f"{meta.n_blocks} kernel blocks do not split evenly over "
-                f"{n_dev} devices; use an n_shards that is a multiple of "
-                f"the mesh size")
+                f"{n_dev} devices; serving the kernel state from one device "
+                f"(Partition.padded_to_blocks aligns a layout)",
+                RuntimeWarning, stacklevel=3)
+            return
 
         def put(x, axis):
             spec = [None] * x.ndim
@@ -489,7 +497,7 @@ class ShardedGamIndex:
         mo = 0 if exact else (self.min_overlap if min_overlap is None
                               else int(min_overlap))
         q = int(np.asarray(users).shape[0])
-        results, offsets = [], []
+        launched, offsets = [], []
         for g in range(len(self.metas)):
             units = self._launch_units(g)
             for off, meta, fac, alive in units:
@@ -499,10 +507,17 @@ class ShardedGamIndex:
                 u, tq, qm = args
                 with tracer.span("gam_retrieve", group=g, bn=meta.bn,
                                  n_rows=meta.n_rows):
-                    results.append(gam_retrieve(
+                    res = gam_retrieve(
                         u, fac, tq, qm, meta, kappa, min_overlap=mo,
-                        alive=alive, rerank_factor=self.rerank_factor))
+                        alive=alive, rerank_factor=self.rerank_factor,
+                        rerank=False)
+                launched.append((res, meta.quantize == "int8", u, fac))
                 offsets.append(self.partition.group_rows(g)[0] + off)
+        # int8 pools are re-ranked on the host only once every launch is
+        # queued: the re-rank waits on its launch, and would otherwise keep
+        # a mesh's devices from running together
+        results = [rerank_pool(res, u, fac, kappa) if int8 else res
+                   for res, int8, u, fac in launched]
         skips = (np.concatenate([expand_tile_skips(r.skipped, q)
                                  for r in results], axis=1)
                  if collect_tile_skips and results else None)

@@ -396,6 +396,17 @@ def test_partition_validation_is_loud():
         Partition((10, 10), (8,), (16,))
 
 
+def test_partition_padded_to_blocks_appends_dead_blocks_to_last_shard():
+    part = Partition.from_lengths((100, 650, 250), (64, 64, 64))  # 17 blocks
+    pad = part.padded_to_blocks(4)
+    assert pad.lengths == part.lengths and pad.bns == part.bns
+    assert pad.caps == (128, 704, 256 + 3 * 64)
+    assert pad.padded_to_blocks(4) == pad == pad.padded_to_blocks(1)
+    # several bn-groups are not mesh-placed: left as they are
+    hetero = Partition.from_lengths((100, 650), (16, 64))
+    assert hetero.padded_to_blocks(4) == hetero
+
+
 def test_repartitioner_balances_weights_and_sizes_bn():
     """Heavy head of the catalog -> shorter head shards with narrower
     blocks; every shard carries ~equal total weight."""
@@ -538,5 +549,43 @@ def test_four_device_mesh_serves_each_shard_from_its_device(quantize):
     for exact in (False, True):
         got, want = (s.query(users, 10, exact=exact) for s in svcs)
         np.testing.assert_array_equal(got.ids, want.ids)
-        assert_scores_match(got.scores, want.scores)
+        # the same (bq, bn) tiles on every device: scores exactly equal
+        np.testing.assert_array_equal(got.scores, want.scores)
+        np.testing.assert_array_equal(got.n_scored, want.n_scored)
+
+
+@pytest.mark.skipif(jax.device_count() < 4,
+                    reason="needs 4 devices (XLA_FLAGS host platform count)")
+@pytest.mark.parametrize("skewed", [False, True])
+def test_four_device_mesh_keeps_its_placement_through_compaction(skewed):
+    """Layouts whose block count does not split over four devices — three
+    uniform shards, or a one-bn skewed plan with ragged caps — are padded
+    with dead blocks at build and at the background compaction, so the
+    mesh deployment keeps one launch per device and the one-device
+    layout's answers."""
+    from repro.launch.mesh import make_index_mesh
+
+    items = _factors(1000, 16, 41)
+    users = _factors(9, 16, 42)
+    spec = RetrieverSpec(cfg=CFG, backend="sharded", n_shards=3,
+                         min_overlap=2, kappa=10, bucket=512)
+    svcs = [open_retriever(spec, items=items, mesh=make_index_mesh(4)),
+            open_retriever(spec, items=items)]
+    for svc in svcs:
+        svc.upsert([5, 1500], _factors(2, 16, 43))
+        svc.delete([8])
+        part = (Partition.from_lengths((100, svc.n_items - 350, 250),
+                                       (64, 64, 64)) if skewed else None)
+        svc.compact(async_=True, partition=part)
+        while svc.maintenance_stats()["compaction"]["active"]:
+            svc.compaction_step()
+    mesh_base, one_base = svcs[0].base, svcs[1].base
+    bn = one_base.partition.bns[0]
+    assert one_base.partition.n_rows // bn % 4       # 6 or 17 blocks
+    assert mesh_base.partition.n_rows // bn % 4 == 0
+    assert len(mesh_base._launch_units(0)) == 4
+    for exact in (False, True):
+        got, want = (s.query(users, 10, exact=exact) for s in svcs)
+        np.testing.assert_array_equal(got.ids, want.ids)
+        np.testing.assert_array_equal(got.scores, want.scores)
         np.testing.assert_array_equal(got.n_scored, want.n_scored)

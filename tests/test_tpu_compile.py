@@ -14,7 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.mapping import GamConfig
-from repro.kernels.gam_retrieve import _gam_retrieve
+from repro.kernels.gam_retrieve import SKIP_MAP_TILES, _gam_retrieve
 from repro.kernels.gam_score import gam_score
 
 # chip_smoke.py's catalog: 2^20 items at d=64, parse-tree patterns
@@ -65,14 +65,19 @@ def _retrieve(sharding, *, n_pad, q, kappa, bn, quantized, min_overlap):
         words=WORDS, n_pad=n_pad, interpret=False, loop_merge=True).compile()
 
 
-@pytest.mark.parametrize("quantized,kappa,q,min_overlap", [
-    (False, 10, 32, 2),       # phase A microbatch, pruned
-    (False, 10, 64, 0),       # phase A check batch, exact path
-    (True, 40, 32, 2),        # phase B: int8 slab, kappa x rerank pool
+@pytest.mark.parametrize("n_pad,quantized,kappa,q,min_overlap", [
+    (N, False, 10, 32, 2),        # phase A microbatch, pruned
+    (N, False, 10, 64, 0),        # phase A check batch, exact path
+    (N, True, 40, 32, 2),         # phase B: int8 slab, kappa x rerank pool
+    (4 * N, False, 10, 64, 2),    # four-chip phase's one-device comparison
+    # the largest launch SKIP_MAP_TILES allows over 2^22 rows, both paths
+    (4 * N, False, 10, 256, 2),
+    (4 * N, True, 40, 256, 2),
 ])
-def test_gam_retrieve_compiles_for_v5e(one_chip, quantized, kappa, q,
+def test_gam_retrieve_compiles_for_v5e(one_chip, n_pad, quantized, kappa, q,
                                        min_overlap):
-    compiled = _retrieve(one_chip, n_pad=N, q=q, kappa=kappa, bn=BN,
+    assert (q // 32) * (n_pad // BN) <= SKIP_MAP_TILES
+    compiled = _retrieve(one_chip, n_pad=n_pad, q=q, kappa=kappa, bn=BN,
                          quantized=quantized, min_overlap=min_overlap)
     assert "tpu_custom_call" in compiled.as_text()
 
