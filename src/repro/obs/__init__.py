@@ -1,8 +1,9 @@
 """Observability layer: streaming histograms, request tracing, the event
 journal and metric exporters.
 
-A deliberately light package — numpy + stdlib only, no jax and no imports
-from the rest of ``repro`` — so the service tier (``repro.service``), the
+A deliberately light package — numpy + stdlib, no imports from the rest of
+``repro``, and jax only for the tracer's profiler annotations (imported
+when a ``Tracer`` is built) — so the service tier (``repro.service``), the
 retriever backends and the launchers can all depend on it without cycles,
 and recording on the request hot path never touches device state.
 """

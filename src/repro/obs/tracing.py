@@ -14,6 +14,16 @@ same monotonically increasing ``trace_id`` to the same request.  Each host
 annotates its spans with its ``host`` id; concatenating the per-host JSONL
 files and grouping on ``trace_id`` reassembles the distributed trace
 (see ``docs/deployment.md``).
+
+Every sampled span that :meth:`Tracer.trace`, :meth:`Tracer.span` or
+:meth:`Tracer.trace_or_span` opens is mirrored, for the same interval and
+with the same nesting, by a ``jax.profiler.TraceAnnotation`` named
+``repro.<span name>`` (attributes stay on the in-memory :class:`Span`).
+The annotation records only while a profiler session is active, so a
+profile (``jax.profiler.trace``) carries the request's stages on the host
+timeline beside the device's operations; without one it costs well under
+a microsecond per span.  Post-hoc intervals (:meth:`Tracer.record_span`)
+are not mirrored: they elapsed before the call that records them.
 """
 from __future__ import annotations
 
@@ -91,6 +101,10 @@ class Tracer:
         self._stack: list[Span] = []
         self.n_started = 0          # every root, sampled or not (= trace ids)
         self.n_sampled = 0
+        # imported here, not at module import: the package stays free of
+        # jax for components that only ever hold NOOP_TRACER
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
 
     @property
     def active(self) -> bool:
@@ -113,14 +127,16 @@ class Tracer:
             yield NOOP_SPAN
             return
         self.n_sampled += 1
-        sp = Span(name, self.clock(), tid, host=self.host, attrs=dict(attrs))
-        self._stack.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.t1 = self.clock()
-            self._stack.pop()
-            self.finished.append(sp)
+        with self._annotation(f"repro.{name}"):
+            sp = Span(name, self.clock(), tid, host=self.host,
+                      attrs=dict(attrs))
+            self._stack.append(sp)
+            try:
+                yield sp
+            finally:
+                sp.t1 = self.clock()
+                self._stack.pop()
+                self.finished.append(sp)
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs: Any):
@@ -130,15 +146,16 @@ class Tracer:
         if not self._stack:
             yield NOOP_SPAN
             return
-        sp = Span(name, self.clock(), self._stack[-1].trace_id,
-                  host=self.host, attrs=dict(attrs))
-        self._stack[-1].children.append(sp)
-        self._stack.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.t1 = self.clock()
-            self._stack.pop()
+        with self._annotation(f"repro.{name}"):
+            sp = Span(name, self.clock(), self._stack[-1].trace_id,
+                      host=self.host, attrs=dict(attrs))
+            self._stack[-1].children.append(sp)
+            self._stack.append(sp)
+            try:
+                yield sp
+            finally:
+                sp.t1 = self.clock()
+                self._stack.pop()
 
     @contextlib.contextmanager
     def trace_or_span(self, name: str, **attrs: Any):

@@ -485,9 +485,10 @@ class ShardedRetriever(Retriever):
         # bit-identical to computing below.
         cache_keys = None
         if self.cache is not None and q > 0:
-            cache_keys = [ResultCache.key(users[i], kappa, exact)
-                          for i in range(q)]
-            rows = self.cache.get_batch(cache_keys)
+            with self.tracer.span("cache_lookup"):
+                cache_keys = [ResultCache.key(users[i], kappa, exact)
+                              for i in range(q)]
+                rows = self.cache.get_batch(cache_keys)
             if rows is not None:
                 return self._answer_from_cache(rows, q, kappa, explain)
         # degrade-ladder selection: pure function of budget / cost estimate
@@ -527,7 +528,7 @@ class ShardedRetriever(Retriever):
                 with self.tracer.span("delta", n_delta=len(self.delta)):
                     d_scores, d_ids, d_cand = self.delta.query(
                         users_j, tau, q_mask, kappa, exact=eff_exact,
-                        min_overlap=eff_overlap)
+                        min_overlap=eff_overlap, tracer=self.tracer)
 
             with self.tracer.span("merge", kappa=kappa):
                 cat_scores = np.concatenate([b_scores, d_scores], axis=1)
@@ -587,9 +588,10 @@ class ShardedRetriever(Retriever):
             # memoize the full-service answer per row, tagged with the
             # current cache version (degraded answers are never cached —
             # they are not what the uncached full path would return)
-            for i, key in enumerate(cache_keys):
-                self.cache.put(key, ids_out[i], sc_out[i],
-                               int(n_cand[i]), float(discard[i]))
+            with self.tracer.span("cache_fill"):
+                for i, key in enumerate(cache_keys):
+                    self.cache.put(key, ids_out[i], sc_out[i],
+                                   int(n_cand[i]), float(discard[i]))
         return RetrievalResult(
             ids=ids_out, scores=sc_out,
             n_scored=np.asarray(n_cand, np.int64),

@@ -22,7 +22,9 @@ from repro.core.inverted_index import DeviceIndex
 from repro.core.mapping import GamConfig, sparse_map
 from repro.kernels.gam_retrieve import build_retrieval_meta
 from repro.kernels.ops import gam_retrieve
+from repro.obs.tracing import NOOP_TRACER
 from repro.retriever.types import dedupe_last_write
+from repro.service.sharded_index import collect_launch
 
 __all__ = ["DeltaSegment"]
 
@@ -118,11 +120,14 @@ class DeltaSegment:
     # ---------------------------------------------------------- query
 
     def query(self, users, q_tau, q_mask, kappa: int, *,
-              exact: bool = False, min_overlap: int | None = None):
+              exact: bool = False, min_overlap: int | None = None,
+              tracer=NOOP_TRACER):
         """-> (scores (Q, kk) f32 with NEG pads, catalog ids (Q, kk) int64)
         over the delta rows only; kk = min(kappa, len(self)).
         ``min_overlap`` overrides the segment's prune threshold (the QoS
-        degrade ladder raises it under deadline pressure)."""
+        degrade ladder raises it under deadline pressure).  ``tracer``
+        times the wait for the launch and the int8 re-rank, as the main
+        segment does (``sharded_index.collect_launch``)."""
         if not len(self):
             q = np.asarray(users).shape[0]
             return (np.zeros((q, 0), np.float32), np.zeros((q, 0), np.int64),
@@ -136,7 +141,9 @@ class DeltaSegment:
                            self._meta, kk,
                            min_overlap=0 if exact else mo,
                            alive=self._alive,
-                           rerank_factor=self.rerank_factor)
+                           rerank_factor=self.rerank_factor, rerank=False)
+        res = collect_launch(res, users, self._factors_dev, kk,
+                             self._meta.quantize == "int8", tracer)
         n_cand = np.asarray(res.blk_counts, np.int64).sum(axis=1)
         # empty (NEG-scored) slots carry row -1; clip before the id gather
         # (the caller replaces their ids via the NEG-score filter anyway)

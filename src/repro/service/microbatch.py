@@ -254,6 +254,7 @@ class Microbatcher:
                 waits = [t_fire - p.t_submit for p in batch]
                 service = t_done - t_fire
                 root.set(queue_wait_max_s=max(waits), service_s=service)
+                self._answer(batch, ids, scores, info, waits, service)
         except NoLiveReplica:
             # the round was unservable (every replica of some slice down or
             # faulted): the batch becomes typed sheds, the server keeps
@@ -265,6 +266,11 @@ class Microbatcher:
                 self._record_shed(shed)
             self._evict_overflow()
             return
+
+    def _answer(self, batch, ids, scores, info, waits, service) -> None:
+        """Write each request's :class:`QueryResult` and the batch's
+        metrics (inside the ``request_batch`` root, which thereby times
+        the microbatcher's own work around the query)."""
         lats = [w + service for w in waits]
         degraded = bool(info.get("degraded", False))
         rung = info.get("degrade_rung")

@@ -59,7 +59,7 @@ from repro.obs.tracing import NOOP_TRACER
 from repro.service.repartition import Partition
 
 __all__ = ["ShardTopK", "ShardedGamIndex", "build_group_meta",
-           "build_shard_segment"]
+           "build_shard_segment", "collect_launch"]
 
 
 @dataclasses.dataclass
@@ -72,6 +72,26 @@ class ShardTopK:
     tiles_skipped_frac: float = 0.0  # fraction of (Q_blk, N_blk) tiles pruned
     tile_skips: np.ndarray | None = None  # (Q, n_blocks) bool prepass skips
                                           # (explain-only; None by default)
+
+
+def collect_launch(res, users, factors, kappa: int, int8: bool,
+                   tracer=NOOP_TRACER):
+    """The host's side of one queued ``gam_retrieve(..., rerank=False)``
+    launch: -> its result, an int8 pool re-ranked to ``kappa``.
+
+    Inside a sampled trace a ``device_wait`` span blocks on the launch
+    where the host first reads it, so the wait is told apart from host
+    work; untraced, that first read waits instead, and launches and waits
+    keep the same order either way.  An int8 pool's exact re-rank
+    (:func:`rerank_pool`: a device gather, the transfer, a per-query numpy
+    loop) runs under a ``rerank`` span."""
+    if tracer.active:
+        with tracer.span("device_wait"):
+            jax.block_until_ready(res)
+    if int8:
+        with tracer.span("rerank"):
+            res = rerank_pool(res, users, factors, kappa)
+    return res
 
 
 # -------------------------------------------------------- staged build units
@@ -486,9 +506,10 @@ class ShardedGamIndex:
         ``exact=True`` scores every live row through the same kernel
         (``min_overlap=0``) — the brute-force reference path.
 
-        ``tracer`` wraps each per-group kernel launch and the host merge in
-        spans; ``collect_tile_skips`` additionally expands the kernel's
-        per-query-block skip map to a per-query (Q, n_blocks) bool in
+        ``tracer`` wraps each launch's enqueue (``gam_retrieve``), the wait
+        for it and its int8 re-rank (:func:`collect_launch`) and the host
+        merge in spans; ``collect_tile_skips`` additionally expands the
+        kernel's per-query-block skip map to a per-query (Q, n_blocks) bool in
         ``ShardTopK.tile_skips`` (host-side numpy over existing outputs —
         the device computation and the answer are identical either way)."""
         tracer = NOOP_TRACER if tracer is None else tracer
@@ -516,7 +537,7 @@ class ShardedGamIndex:
         # int8 pools are re-ranked on the host only once every launch is
         # queued: the re-rank waits on its launch, and would otherwise keep
         # a mesh's devices from running together
-        results = [rerank_pool(res, u, fac, kappa) if int8 else res
+        results = [collect_launch(res, u, fac, kappa, int8, tracer)
                    for res, int8, u, fac in launched]
         skips = (np.concatenate([expand_tile_skips(r.skipped, q)
                                  for r in results], axis=1)

@@ -1,10 +1,13 @@
 """Observability layer: streaming histograms, tracing, events, exporters
 (tests for src/repro/obs/ and the ServiceMetrics rebuild on top of it)."""
+import glob
 import json
 import math
 
+import jax
 import numpy as np
 import pytest
+from conftest import CFG, unit_factors
 
 from repro.obs import (
     NOOP_SPAN,
@@ -16,6 +19,7 @@ from repro.obs import (
     histogram_to_prometheus,
     snapshot_to_prometheus,
 )
+from repro.retriever import RetrieverSpec, open_retriever
 from repro.service import ServiceMetrics
 
 
@@ -262,6 +266,160 @@ def test_noop_tracer_contract():
         assert sp is NOOP_SPAN
     NOOP_TRACER.record_span("d", 0.0, 1.0)
     assert NOOP_TRACER.active is False
+
+
+# ------------------------------------------- the tracer on the served path
+
+
+def _served(tracer=None, *, quantize="none", cache=0):
+    """A small ``sharded`` service: two shards and a delta of two rows."""
+    spec = RetrieverSpec(cfg=CFG, backend="sharded", n_shards=2,
+                         min_overlap=1, kappa=5, batch_size=4, bucket=512,
+                         quantize=quantize, cache_capacity=cache)
+    r = open_retriever(spec, items=unit_factors(256, CFG.k, 20),
+                       tracer=tracer)
+    r.upsert([1000, 1001], unit_factors(2, CFG.k, 21))
+    return r
+
+
+def _batch(r, seed):
+    """One full microbatch (the size trigger fires it) -> its results."""
+    rids = [r.batcher.submit(u) for u in unit_factors(4, CFG.k, seed)]
+    return [r.batcher.result(i) for i in rids]
+
+
+def _profile(log_dir, fn):
+    """Run ``fn`` under the JAX profiler -> [(plane, line, name, t0, t1)]
+    of every host event it recorded."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path)
+    return [(plane.name, line.name, e.name, e.start_ns,
+             e.start_ns + e.duration_ns)
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def _names(span):
+    return [span.name] + [n for c in span.children for n in _names(c)]
+
+
+def test_profiler_mirrors_the_span_tree(tmp_path):
+    tracer = Tracer()
+    r = _served(tracer, quantize="int8", cache=64)
+    _batch(r, 30)                        # programs compile before the profile
+    events = [e for e in _profile(tmp_path, lambda: _batch(r, 31))
+              if e[2].startswith("repro.")]
+    assert {(plane, line) for plane, line, *_ in events} == \
+        {("/host:CPU", "python")}
+    # one annotation per span of the batch's tree, post-hoc queue_wait aside,
+    # named by the span alone (its attributes stay on the Span)
+    root = tracer.finished[-1]
+    assert sorted(n for _, _, n, _, _ in events) == sorted(
+        "repro." + n for n in _names(root) if n != "queue_wait")
+
+    def only(name, within=None):
+        found = [(t0, t1) for _, _, n, t0, t1 in events
+                 if n == "repro." + name and (within is None or
+                                              within[0] <= t0 <= within[1])]
+        assert found, name
+        return found[0]
+
+    outer = only("request_batch")
+    for name in ("flush", "query", "base", "device_wait"):
+        inner = only(name, outer)
+        assert outer[0] <= inner[0] and inner[1] <= outer[1], name
+        outer = inner
+    base = only("base")
+    assert only("rerank", base)[1] <= base[1]
+    cache = only("cache_lookup")
+    assert cache[1] <= only("query")[0]
+
+
+def test_noop_and_unsampled_traces_emit_no_annotation(tmp_path):
+    from jax.profiler import TraceAnnotation
+
+    unsampled = Tracer(sample_rate=0.0)
+
+    def run():
+        with NOOP_TRACER.trace("a"), NOOP_TRACER.span("b"):
+            pass
+        with unsampled.trace("root"), unsampled.span("child"):
+            pass
+        with Tracer().span("orphan"):     # a span outside any trace
+            pass
+        with TraceAnnotation("marker"):
+            pass
+
+    names = [n for _, _, n, _, _ in _profile(tmp_path, run)]
+    assert "marker" in names
+    assert not [n for n in names if n.startswith("repro.")]
+
+
+@pytest.mark.parametrize("quantize", ["none", "int8"])
+@pytest.mark.parametrize("cache", [0, 64], ids=["no_cache", "cache"])
+def test_span_tree_of_a_served_batch(quantize, cache):
+    tracer = Tracer()
+    r = _served(tracer, quantize=quantize, cache=cache)
+    _batch(r, 40)
+    root = tracer.finished[-1]
+    assert [c.name for c in root.children] == ["queue_wait", "flush"]
+    [flush] = root.children[1:]
+    assert [c.name for c in flush.children] == (
+        ["cache_lookup", "query", "cache_fill"] if cache else ["query"])
+    if not cache:
+        assert not [n for n in _names(root) if n.startswith("cache")]
+    [query] = flush.find("query")
+    assert [c.name for c in query.children] == ["map", "base", "delta",
+                                                "merge"]
+    [base] = query.find("base")
+    [delta] = query.find("delta")
+    launches = len(base.find("gam_retrieve"))
+    assert launches >= 1
+    # every launch is waited on, and an int8 pool re-ranked, once
+    assert len(base.find("device_wait")) == launches
+    assert len(delta.find("device_wait")) == 1
+    reranks = [len(base.find("rerank")), len(delta.find("rerank"))]
+    assert reranks == ([launches, 1] if quantize == "int8" else [0, 0])
+    assert all(s.t1 is not None for s in root.find("device_wait"))
+
+
+def test_a_cache_hit_batch_gives_the_single_cache_span():
+    tracer = Tracer()
+    r = _served(tracer, cache=64)
+    u = unit_factors(4, CFG.k, 41)
+    r.query(u)                                    # fills the cache
+    with tracer.trace("request"):                 # as a caller's root
+        hit = r.query(u)
+    root = tracer.finished[-1]
+    assert [c.name for c in root.children] == ["cache_lookup", "query"]
+    assert _names(root.children[1]) == ["query", "cache"]
+    assert r.cache.stats()["hits"] == 4 and hit.explain is None
+    r.query(u)                                    # a direct call: no lookup
+    assert _names(tracer.finished[-1]) == ["query", "cache"]
+
+
+@pytest.mark.parametrize("quantize", ["none", "int8"])
+def test_tracing_leaves_the_answers_unchanged(quantize):
+    on = _served(Tracer(), quantize=quantize, cache=64)
+    off = _served(None, quantize=quantize, cache=64)
+    assert off.tracer is NOOP_TRACER
+    for seed in (50, 51):
+        for a, b in zip(_batch(on, seed), _batch(off, seed)):
+            np.testing.assert_array_equal(a.ids, b.ids)
+            np.testing.assert_array_equal(a.scores, b.scores)
+    u = unit_factors(3, CFG.k, 52)
+    for exact in (False, True):
+        a, b = on.query(u, exact=exact), off.query(u, exact=exact)
+        np.testing.assert_array_equal(a.ids, b.ids)
+        np.testing.assert_array_equal(a.scores, b.scores)
+    assert len(on.tracer.finished) == 4
 
 
 # ----------------------------------------------------------- EventJournal
