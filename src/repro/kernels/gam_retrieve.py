@@ -13,7 +13,15 @@ over item blocks so HBM output shrinks to O(Q * kappa):
     the posting-table overlap count exactly (tau destinations are unique per
     row, and bucket overflow only ever *removes* table counts for items that
     are then spill-listed — spill rows are unconditional candidates here as
-    in the table path, so the candidate set is bit-identical).
+    in the table path, so the candidate set is bit-identical).  The popcount
+    runs only over the words the batch sets: a query sets a handful of
+    bits, against 258-629 words at k=64-100, so a batch of 32 sets about
+    a fifth of the words or fewer.  Each launch lists the words some query
+    sets (up to :data:`COMPACT_WORDS` of them; a batch that sets more runs
+    the loop over every word), and the kernel loops over those rows of
+    each item block alone.  A word no query sets adds
+    ``popcount(0 & x) = 0`` to every overlap, so the overlaps, and
+    everything decided from them, are exact.
 
   * **Block skipping** — a prepass intersects each query's bits with the
     per-block *union* pattern (posting-derived block metadata built at index
@@ -61,9 +69,9 @@ from repro.compress.quantize import quantize_int8
 from repro.kernels.gam_score import NEG
 
 __all__ = ["RetrievalMeta", "GamRetrieveResult", "RowCapacityError",
-           "SKIP_MAP_TILES", "TOPK_EMPTY_ROW", "build_retrieval_meta",
-           "effective_bq", "expand_tile_skips", "export_topk", "gam_retrieve",
-           "pack_patterns", "rerank_pool"]
+           "COMPACT_WORDS", "SKIP_MAP_TILES", "TOPK_EMPTY_ROW",
+           "build_retrieval_meta", "effective_bq", "expand_tile_skips",
+           "export_topk", "gam_retrieve", "pack_patterns", "rerank_pool"]
 
 # Row sentinel for non-candidate tile entries: larger than any real global row
 # (catalogs < 2^30 rows — enforced by RowCapacityError at build/assembly
@@ -99,6 +107,12 @@ TOPK_EMPTY_ROW = np.int32(np.iinfo(np.int32).max)
 #: 2^17 tiles is Q=256 over 2^22 rows at bn=256, which compiles for a v5e
 #: (tests/test_tpu_compile.py); Q=512 there runs out of SMEM.
 SKIP_MAP_TILES = 1 << 17
+
+#: Width of the compacted overlap loop: a launch whose queries set at
+#: most this many distinct pattern words loops over a list of them, padded
+#: to this width, and others over every word (:func:`_gam_retrieve`).  An
+#: index of at most this many words has the full loop only.
+COMPACT_WORDS = 128
 
 
 def effective_bq(q: int, bq: int = 32) -> int:
@@ -284,6 +298,25 @@ def _overlap(qb, ibT, *, words, fused_words):
     return ov
 
 
+def _overlap_listed(qb, ib_ref, word_ref, *, fused_words):
+    """:func:`_overlap` over the rows of the item block that the launch's
+    word list (SMEM) names: ``qb`` (bq, COMPACT_WORDS) holds the listed
+    words' query columns.  A listed slot that no query sets is zero in
+    every query row and adds ``popcount(0 & x) = 0``, so the sum is the
+    full pattern overlap.  The loop stays unrolled over every slot: on a
+    v5e a rolled loop, or one that skips the unused slots, cost more per
+    word than the slots it saves."""
+    if fused_words:
+        return _overlap(qb, ib_ref[...][word_ref[...]], words=qb.shape[1],
+                        fused_words=True)
+    ov = jnp.zeros((qb.shape[0], ib_ref.shape[1]), jnp.int32)
+    for w in range(qb.shape[1]):
+        ov = ov + jax.lax.population_count(
+            qb[:, w:w + 1] & ib_ref[pl.ds(word_ref[w], 1), :]
+        ).astype(jnp.int32)
+    return ov
+
+
 def _merge_topk(acc_s, acc_r, tile_s, tile_r, *, kappa, loop_merge):
     """Running top-kappa merge under the total order (score desc, row asc).
 
@@ -312,9 +345,12 @@ def _merge_topk(acc_s, acc_r, tile_s, tile_r, *, kappa, loop_merge):
     return jnp.concatenate(sel_s, axis=1), jnp.concatenate(sel_r, axis=1)
 
 
-def _kernel(skip_ref, u_ref, qb_ref, v_ref, *rest,
-            kappa, min_overlap, bn, n_blocks, words, loop_merge, fused_words,
-            quantized=False):
+def _kernel(skip_ref, *refs, kappa, min_overlap, bn, n_blocks, words,
+            loop_merge, fused_words, compact=False, quantized=False):
+    if compact:
+        # the compacted loop's word list (SMEM) follows the skip map
+        word_ref, *refs = refs
+    u_ref, qb_ref, v_ref, *rest = refs
     if quantized:
         # the int8 factor tile's per-block scales (SMEM) precede the bit refs
         sc_ref, ib_ref, sp_ref, al_ref, vals_ref, rows_ref, cnt_ref = rest
@@ -334,8 +370,12 @@ def _kernel(skip_ref, u_ref, qb_ref, v_ref, *rest,
 
     @pl.when(skip_ref[pl.program_id(0) * n_blocks + j] == 0)
     def _tile():
-        ov = _overlap(qb_ref[...], ib_ref[...], words=words,
-                      fused_words=fused_words)
+        if compact:
+            ov = _overlap_listed(qb_ref[...], ib_ref, word_ref,
+                                 fused_words=fused_words)
+        else:
+            ov = _overlap(qb_ref[...], ib_ref[...], words=words,
+                          fused_words=fused_words)
         cand = ((ov >= min_overlap) | (sp_ref[...] != 0)) & (al_ref[...] != 0)
         cnt_ref[...] = jnp.sum(cand.astype(jnp.int32), axis=1, keepdims=True)
         v = v_ref[...]
@@ -362,67 +402,48 @@ class GamRetrieveResult(NamedTuple):
     rows: jax.Array        # (Q, kappa) int32 global rows, -1 in empty slots
     blk_counts: jax.Array  # (Q, n_blocks) int32 candidates per item block
     skipped: jax.Array     # (q_blocks, n_blocks) bool — tiles never scored
+    loop_words: jax.Array  # (q_blocks,) int32 pattern words the overlap
+                           # loop ran over (the listed words, or all)
 
 
-@partial(jax.jit, static_argnames=("kappa", "min_overlap", "bq", "bn",
-                                   "words", "n_pad", "interpret",
-                                   "loop_merge"))
-def _gam_retrieve(users, factors, scales, q_tau, q_mask, alive, ibT, union,
-                  bspill, spill8, *, kappa, min_overlap, bq, bn, words, n_pad,
-                  interpret, loop_merge):
-    """One fused launch.  ``scales`` is None on the f32 path; on the int8
-    path ``factors`` is the quantized (n_pad, k) slab, ``scales`` its
-    (1, n_blocks) per-block dequant scales, and ``kappa`` the rerank POOL
-    width (the caller re-ranks the pool against exact f32 rows).
-
-    Tiling for Mosaic: the skip map and the scales are whole 1-D SMEM
-    arrays indexed by grid position (a (1, 1) block of a 2-D array breaks
-    the (8, 128) block rule), and the per-block candidate counts come out
-    as (n_blocks, Q, 1) so each grid step writes one (bq, 1) column block.
-    The skip map takes 4 bytes per (query block, item block) tile;
-    :func:`gam_retrieve` splits a batch so it stays within
-    :data:`SKIP_MAP_TILES`.
-    """
-    quantized = scales is not None
-    q, k = users.shape
-    bq = effective_bq(q, bq)
-    qp = -(-q // bq) * bq
-    nb = n_pad // bn
-
-    q_bits = _pack_patterns_jnp(q_tau, q_mask, words)
+def _launch(q_bits, word_idx, union, ibT, up, fp, extra, extra_specs,
+            bspill, spill8, al8, *, kappa, min_overlap, bq, bn, interpret,
+            loop_merge):
+    """The block prepass and the kernel over ``q_bits``' pattern words:
+    every word of ``ibT`` (words, n_pad) when ``word_idx`` is None, else
+    ``q_bits`` (qp, w) and ``union`` (nb, w) hold the words ``word_idx``
+    (w,) names.  -> padded (vals, rows, counts) and the (qp / bq, nb) skip
+    map."""
+    qp, k = up.shape
+    nb, words = union.shape
 
     # ---- block prepass: union popcount upper-bounds member overlap --------
+    # (a pad query row sets no bits, so it marks no block possible that the
+    # real rows of its query block do not)
     ub = jnp.sum(jax.lax.population_count(
         q_bits[:, None, :] & union[None, :, :]).astype(jnp.int32), axis=-1)
-    possible = (ub >= min_overlap) | bspill[None, :]            # (q, nb)
-    possible = jnp.pad(possible, ((0, qp - q), (0, 0)))
+    possible = (ub >= min_overlap) | bspill[None, :]            # (qp, nb)
     skip = jnp.logical_not(
         possible.reshape(qp // bq, bq, nb).any(axis=1)).astype(jnp.int32)
 
-    up = jnp.pad(users.astype(jnp.float32), ((0, qp - q), (0, 0)))
-    qbp = jnp.pad(q_bits, ((0, qp - q), (0, 0)))
-    al8 = jnp.pad(alive.astype(jnp.int8), (0, n_pad - alive.shape[0]))[None, :]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    if quantized:
-        fp, extra, extra_specs = factors, [scales.reshape(-1)], [smem]
-    else:
-        fp = factors.astype(jnp.float32)
-        if fp.shape[0] != n_pad:      # a zero-width pad would still copy
-            fp = jnp.pad(fp, ((0, n_pad - fp.shape[0]), (0, 0)))
-        extra, extra_specs = [], []
-
-    vals, rows, cnt = pl.pallas_call(
+    compact = word_idx is not None
+    kernel = pl.pallas_call(
         partial(_kernel, kappa=kappa, min_overlap=min_overlap, bn=bn,
                 n_blocks=nb, words=words, loop_merge=loop_merge,
-                fused_words=interpret, quantized=quantized),
+                fused_words=interpret, compact=compact,
+                quantized=bool(extra)),
         grid=(qp // bq, nb),
         in_specs=[
             smem,
+            *([smem] if compact else []),
             pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, words), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, k), lambda i, j: (j, 0)),
             *extra_specs,
-            pl.BlockSpec((words, bn), lambda i, j: (0, j)),
+            # every word of the item block; a compacted launch reads the
+            # rows word_idx names from it
+            pl.BlockSpec((ibT.shape[0], bn), lambda i, j: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
@@ -437,11 +458,97 @@ def _gam_retrieve(users, factors, scales, q_tau, q_mask, alive, ibT, union,
             jax.ShapeDtypeStruct((nb, qp, 1), jnp.int32),
         ),
         interpret=interpret,
-    )(skip.reshape(-1), up, qbp, fp, *extra, ibT, spill8, al8)
+    )
+    # inside a ``lax.cond`` branch too, the kernel's custom call keeps the
+    # program's name (``%_gam_retrieve.<n>``), which profile readers match
+    with jax.named_scope("_gam_retrieve"):
+        vals, rows, cnt = kernel(skip.reshape(-1),
+                                 *([word_idx] if compact else []), up,
+                                 q_bits, fp, *extra, ibT, spill8, al8)
+    return vals, rows, cnt, skip
+
+
+@partial(jax.jit, static_argnames=("kappa", "min_overlap", "bq", "bn",
+                                   "words", "n_pad", "interpret",
+                                   "loop_merge"))
+def _gam_retrieve(users, factors, scales, q_tau, q_mask, alive, ibT, union,
+                  bspill, spill8, *, kappa, min_overlap, bq, bn, words, n_pad,
+                  interpret, loop_merge):
+    """One fused launch.  ``scales`` is None on the f32 path; on the int8
+    path ``factors`` is the quantized (n_pad, k) slab, ``scales`` its
+    (1, n_blocks) per-block dequant scales, and ``kappa`` the rerank POOL
+    width (the caller re-ranks the pool against exact f32 rows).
+
+    Active-word compaction: the overlap loop and the block prepass run
+    over the pattern words some query of the batch sets, not over all
+    ``words``.  The launch counts the words the padded batch sets; when
+    they are at most :data:`COMPACT_WORDS` (one ``lax.cond`` in this
+    program, so no host sync and no program per batch) it lists them
+    (SMEM), gathers their columns of the query bits and of ``union``, and
+    zeroes the query columns of the list's unused slots.  The kernel then
+    loops over the listed rows of each item block, which it streams in
+    whole.  (Gathering ``ibT``'s rows in HBM instead costs more than it
+    saves: a row is one sublane of every (8, 128) tile.)  A word no query
+    sets adds ``popcount(0 & x) = 0`` to every overlap, and each set word
+    is listed once, so every overlap, and with it the candidates, scores,
+    counts and skip map, is the full loop's, bit for bit.  A batch that
+    sets more words runs the full loop, and an index of at most
+    :data:`COMPACT_WORDS` words compiles the full loop alone.
+
+    Tiling for Mosaic: the skip map and the scales are whole 1-D SMEM
+    arrays indexed by grid position (a (1, 1) block of a 2-D array breaks
+    the (8, 128) block rule), and the per-block candidate counts come out
+    as (n_blocks, Q, 1) so each grid step writes one (bq, 1) column block.
+    The skip map takes 4 bytes per (query block, item block) tile;
+    :func:`gam_retrieve` splits a batch so it stays within
+    :data:`SKIP_MAP_TILES`.
+    """
+    q, k = users.shape
+    bq = effective_bq(q, bq)
+    qp = -(-q // bq) * bq
+
+    q_bits = jnp.pad(_pack_patterns_jnp(q_tau, q_mask, words),
+                     ((0, qp - q), (0, 0)))
+    up = jnp.pad(users.astype(jnp.float32), ((0, qp - q), (0, 0)))
+    al8 = jnp.pad(alive.astype(jnp.int8), (0, n_pad - alive.shape[0]))[None, :]
+    if scales is not None:
+        fp = factors
+        extra = [scales.reshape(-1)]
+        extra_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
+    else:
+        fp = factors.astype(jnp.float32)
+        if fp.shape[0] != n_pad:      # a zero-width pad would still copy
+            fp = jnp.pad(fp, ((0, n_pad - fp.shape[0]), (0, 0)))
+        extra, extra_specs = [], []
+
+    launch = partial(_launch, ibT=ibT, up=up, fp=fp, extra=extra,
+                     extra_specs=extra_specs, bspill=bspill, spill8=spill8,
+                     al8=al8, kappa=kappa, min_overlap=min_overlap, bq=bq,
+                     bn=bn, interpret=interpret, loop_merge=loop_merge)
+    active = jnp.any(q_bits != 0, axis=0)        # the words the batch sets
+    n_active = jnp.sum(active, dtype=jnp.int32)
+
+    def full():
+        return launch(q_bits, None, union)
+
+    def compacted():
+        idx = jnp.nonzero(active, size=COMPACT_WORDS, fill_value=0)[0]
+        used = jnp.arange(COMPACT_WORDS) < n_active
+        return launch(jnp.where(used, q_bits[:, idx], jnp.uint32(0)),
+                      idx, union[:, idx])
+
+    if words <= COMPACT_WORDS:
+        vals, rows, cnt, skip = full()
+        width = jnp.int32(words)
+    else:
+        fits = n_active <= COMPACT_WORDS
+        vals, rows, cnt, skip = jax.lax.cond(fits, compacted, full)
+        width = jnp.where(fits, COMPACT_WORDS, words)
 
     vals = vals[:q]
     rows = jnp.where(vals <= NEG / 2, -1, rows[:q])
-    return GamRetrieveResult(vals, rows, cnt[:, :q, 0].T, skip == 1)
+    return GamRetrieveResult(vals, rows, cnt[:, :q, 0].T, skip == 1,
+                             jnp.full((qp // bq,), width, jnp.int32))
 
 
 def rerank_pool(pool_res: GamRetrieveResult, users, factors,
@@ -476,7 +583,8 @@ def rerank_pool(pool_res: GamRetrieveResult, users, factors,
         out_r[qi] = np.where(key_rows[order] == empty_key, -1,
                              rows_p[qi][order])
     return GamRetrieveResult(jnp.asarray(out_s), jnp.asarray(out_r),
-                             pool_res.blk_counts, pool_res.skipped)
+                             pool_res.blk_counts, pool_res.skipped,
+                             pool_res.loop_words)
 
 
 def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,

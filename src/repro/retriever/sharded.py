@@ -667,7 +667,8 @@ class ShardedRetriever(Retriever):
         ids = self.base.rows_to_ids(np.asarray(res.rows), scores)
         stats = {"shard_candidates": np.asarray(res.shard_candidates),
                  "block_candidates": res.block_candidates,
-                 "tiles_skipped_frac": res.tiles_skipped_frac}
+                 "tiles_skipped_frac": res.tiles_skipped_frac,
+                 "active_words_frac": res.active_words_frac}
         if explain:
             stats["tile_skips"] = res.tile_skips
         return scores, ids, stats
@@ -742,9 +743,9 @@ class ShardedRetriever(Retriever):
             posting_load=self.base.posting_load().tolist(),
             metrics=self.metrics.snapshot(),
         )
-        if "tiles_skipped_frac" in self._last_query_stats:
-            out["tiles_skipped_frac"] = (
-                self._last_query_stats["tiles_skipped_frac"])
+        for key in ("tiles_skipped_frac", "active_words_frac"):
+            if key in self._last_query_stats:
+                out[key] = self._last_query_stats[key]
         if self.cache is not None:
             out["result_cache"] = self.cache.stats()
         return out

@@ -70,6 +70,8 @@ class ShardTopK:
     shard_candidates: np.ndarray  # (Q, S) per-shard candidate counts
     block_candidates: np.ndarray | None = None  # (Q, n_blocks) per-block
     tiles_skipped_frac: float = 0.0  # fraction of (Q_blk, N_blk) tiles pruned
+    active_words_frac: float = 1.0   # pattern words the overlap loop ran
+                                     # over, as a fraction of all words
     tile_skips: np.ndarray | None = None  # (Q, n_blocks) bool prepass skips
                                           # (explain-only; None by default)
 
@@ -506,7 +508,8 @@ class ShardedGamIndex:
         ``exact=True`` scores every live row through the same kernel
         (``min_overlap=0``) — the brute-force reference path.
 
-        ``tracer`` wraps each launch's enqueue (``gam_retrieve``), the wait
+        ``tracer`` wraps each launch's enqueue (``gam_retrieve``, which
+        takes the launch's ``active_words_frac`` once it is read), the wait
         for it and its int8 re-rank (:func:`collect_launch`) and the host
         merge in spans; ``collect_tile_skips`` additionally expands the
         kernel's per-query-block skip map to a per-query (Q, n_blocks) bool in
@@ -518,7 +521,7 @@ class ShardedGamIndex:
         mo = 0 if exact else (self.min_overlap if min_overlap is None
                               else int(min_overlap))
         q = int(np.asarray(users).shape[0])
-        launched, offsets = [], []
+        launched, offsets, spans = [], [], []
         for g in range(len(self.metas)):
             units = self._launch_units(g)
             for off, meta, fac, alive in units:
@@ -527,18 +530,27 @@ class ShardedGamIndex:
                     args = jax.device_put(args, fac.sharding)
                 u, tq, qm = args
                 with tracer.span("gam_retrieve", group=g, bn=meta.bn,
-                                 n_rows=meta.n_rows):
+                                 n_rows=meta.n_rows) as sp:
                     res = gam_retrieve(
                         u, fac, tq, qm, meta, kappa, min_overlap=mo,
                         alive=alive, rerank_factor=self.rerank_factor,
                         rerank=False)
                 launched.append((res, meta.quantize == "int8", u, fac))
+                spans.append((sp, meta.words))
                 offsets.append(self.partition.group_rows(g)[0] + off)
         # int8 pools are re-ranked on the host only once every launch is
         # queued: the re-rank waits on its launch, and would otherwise keep
         # a mesh's devices from running together
         results = [collect_launch(res, u, fac, kappa, int8, tracer)
                    for res, int8, u, fac in launched]
+        # the compacted overlap loop's width, per launch and over the batch
+        looped, total = 0, 0
+        for r, (sp, words) in zip(results, spans):
+            lw = np.asarray(r.loop_words)
+            sp.set(active_words_frac=float(lw.mean()) / words)
+            looped += int(lw.sum())
+            total += lw.size * words
+        words_frac = looped / max(total, 1)
         skips = (np.concatenate([expand_tile_skips(r.skipped, q)
                                  for r in results], axis=1)
                  if collect_tile_skips and results else None)
@@ -550,6 +562,7 @@ class ShardedGamIndex:
                              shard_candidates=self._shard_candidates(blk),
                              block_candidates=blk,
                              tiles_skipped_frac=float(res.skipped.mean()),
+                             active_words_frac=words_frac,
                              tile_skips=skips)
         with tracer.span("group_merge", n_launches=len(results)):
             exported = [export_topk(r.vals, r.rows, offset=off)
@@ -568,6 +581,7 @@ class ShardedGamIndex:
                          shard_candidates=self._shard_candidates(blk),
                          block_candidates=blk,
                          tiles_skipped_frac=skipped / max(tiles, 1),
+                         active_words_frac=words_frac,
                          tile_skips=skips)
 
     def query_dense_reference(self, users: jax.Array, q_tau: jax.Array,

@@ -16,7 +16,7 @@ import pytest
 from conftest import CFG, assert_scores_match, unit_factors as _factors
 
 from repro.core.inverted_index import DeviceIndex
-from repro.core.mapping import sparse_map
+from repro.core.mapping import GamConfig, sparse_map
 from repro.core.retrieval import masked_topk
 from repro.retriever import RetrieverSpec, open_retriever
 from repro.kernels import ref
@@ -272,3 +272,137 @@ def test_batch_split_at_skip_map_bound_keeps_results(quantize, monkeypatch):
         late = gr.rerank_pool(pool, users, items, 10)
         for a, b in zip(late, whole):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# --------------------------------------------------- active-word compaction
+
+# k=48 parse-tree patterns span 146 words, more than COMPACT_WORDS
+CFG48 = GamConfig(k=48, scheme="parse_tree", threshold=0.2)
+WORDS48 = -(-CFG48.p // 32)
+
+
+def _full_width(run):
+    """``run()`` with the overlap loop over every pattern word, the program
+    as it was before the compaction."""
+    gr = importlib.import_module("repro.kernels.gam_retrieve")
+    saved = gr.COMPACT_WORDS
+    gr._gam_retrieve.clear_cache()
+    gr.COMPACT_WORDS = WORDS48         # an index this narrow: full loop only
+    try:
+        return run()
+    finally:
+        gr.COMPACT_WORDS = saved
+        gr._gam_retrieve.clear_cache()
+
+
+def _batch_setting(active, tau, mask, items, q, n_pad_rows, seed):
+    """A batch of ``q`` rows whose real queries set exactly ``active``
+    distinct pattern words: each real row keeps an item's pattern bits
+    that fall in a chosen word set, and fillers cover the set's other
+    words.  The last ``n_pad_rows`` rows are the service's pads (zero
+    vector, no pattern bits)."""
+    rng = np.random.default_rng(seed)
+    # word 0 is in the set: the list's unused slots name word 0 too
+    words = (np.concatenate([[0], 1 + rng.choice(
+        WORDS48 - 1, size=active - 1, replace=False)]) if active
+        else np.zeros(0, np.int64))
+    k = tau.shape[1]
+    users = np.zeros((q, k), np.float32)
+    q_tau = np.zeros((q, k), np.int32)
+    q_mask = np.zeros((q, k), bool)
+    real = q - n_pad_rows if active else 0
+    for i in range(real):
+        r = rng.integers(len(items))
+        users[i] = items[r]
+        q_tau[i] = tau[r]
+        q_mask[i] = mask[r] & np.isin(tau[r] // 32, words)
+    covered = set((q_tau[q_mask] // 32).tolist())
+    row = 0
+    for w in sorted(set(words.tolist()) - covered):
+        while q_mask[row % real].all():
+            row += 1
+        i = row % real
+        j = int(np.flatnonzero(~q_mask[i])[0])
+        set_in_w = tau[mask][tau[mask] // 32 == w]    # a bit items set
+        q_tau[i, j] = (rng.choice(set_in_w) if set_in_w.size else
+                       32 * w + rng.integers(0, min(32, CFG48.p - 32 * w)))
+        q_mask[i, j] = True
+        row += 1
+    got = np.unique(q_tau[q_mask] // 32).size
+    assert got == active, (got, active)
+    return users, q_tau, q_mask
+
+
+@pytest.mark.parametrize("active,spill,mo,quantize,delta", [
+    pytest.param(1, False, 1, "none", False, id="one-word"),
+    pytest.param(40, False, 2, "none", False, id="few-words"),
+    pytest.param(127, False, 2, "none", False, id="below-width"),
+    pytest.param(128, False, 2, "none", False, id="at-width"),
+    pytest.param(129, False, 2, "none", False, id="above-width-full-loop"),
+    pytest.param(0, False, 2, "none", False, id="all-pad-batch"),
+    pytest.param(40, True, 2, "none", False, id="spill-rows"),
+    pytest.param(65, False, 0, "none", False, id="exact-min-overlap-0"),
+    pytest.param(65, False, 2, "int8", False, id="int8-pool"),
+    pytest.param(40, False, 2, "none", True, id="delta-segment"),
+])
+def test_active_word_compaction_is_bit_identical(active, spill, mo, quantize,
+                                                 delta):
+    """The overlap loop over the words the batch sets returns the full
+    loop's vals, rows, per-block counts and skip map bit for bit, and runs
+    over COMPACT_WORDS listed words, or over every word when the batch
+    sets more than that."""
+    gr = importlib.import_module("repro.kernels.gam_retrieve")
+    n = 40 if delta else 256
+    items = _factors(n, CFG48.k, 31)
+    tau, mask = (np.array(a) for a in _mapped(items, CFG48))
+    # a third of the items also set a bit of word 0, the word that unused
+    # rung slots gather (parse-tree patterns at k=48 never reach it)
+    some = np.arange(0, n, 3)
+    free = np.argmin(mask, axis=1)[some]
+    tau[some, free], mask[some, free] = 5, True
+    # a delta segment pads its rows to a power of two, bn = min(256, cap)
+    n_rows = 64 if delta else n
+    factors = np.zeros((n_rows, CFG48.k), np.float32)
+    factors[:n] = items
+    meta = build_retrieval_meta(
+        tau, mask, CFG48.p, n_rows=n_rows, bn=64,
+        spill_rows=np.arange(0, n, 9) if spill else None,
+        factors=factors if quantize == "int8" else None, quantize=quantize)
+    q = 32 if delta else 24
+    users, q_tau, q_mask = _batch_setting(active, tau, mask, items, q, 4,
+                                          seed=active + 7)
+
+    def run():
+        return gam_retrieve(users, factors, q_tau, q_mask, meta, 10,
+                            min_overlap=mo, alive=np.arange(n_rows) < n,
+                            bq=8, interpret=True, rerank=False)
+
+    got = run()
+    want = _full_width(run)
+    for field in ("vals", "rows", "blk_counts", "skipped"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                      np.asarray(getattr(want, field)),
+                                      err_msg=field)
+    looped = gr.COMPACT_WORDS if active <= gr.COMPACT_WORDS else WORDS48
+    np.testing.assert_array_equal(np.asarray(got.loop_words), looped)
+    np.testing.assert_array_equal(np.asarray(want.loop_words), WORDS48)
+    scored = int(np.asarray(got.blk_counts).sum())
+    assert scored > 0 if (active or spill or mo == 0) else scored == 0
+
+
+def test_sharded_query_reports_active_words_frac():
+    """``ShardTopK.active_words_frac`` is the share of the index's words
+    the loop ran over, and the ``sharded`` retriever keeps the last
+    query's in ``stats()``."""
+    items = _factors(512, CFG48.k, 33)
+    svc = open_retriever(
+        RetrieverSpec(cfg=CFG48, backend="sharded", n_shards=2,
+                      min_overlap=2, kappa=10, bucket=512), items=items)
+    tau, mask = _mapped(items, CFG48)
+    users, q_tau, q_mask = _batch_setting(100, tau, mask, items, 16, 0,
+                                          seed=34)
+    res = svc.base.query(jnp.asarray(users), jnp.asarray(q_tau),
+                         jnp.asarray(q_mask), 10)
+    assert res.active_words_frac == 128 / WORDS48
+    svc.query(users, 10)
+    assert 0.0 < svc.stats()["active_words_frac"] <= 1.0

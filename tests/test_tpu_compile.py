@@ -8,6 +8,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -49,20 +51,27 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _retrieve(sharding, *, n_pad, q, kappa, bn, quantized, min_overlap):
+def _retrieve(sharding, *, n_pad, q, kappa, bn, quantized, min_overlap,
+              k=K, words=WORDS):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     nb = n_pad // bn
-    args = (s((q, K), jnp.float32),
-            s((n_pad, K), jnp.int8 if quantized else jnp.float32),
+    args = (s((q, k), jnp.float32),
+            s((n_pad, k), jnp.int8 if quantized else jnp.float32),
             s((1, nb), jnp.float32) if quantized else None,
-            s((q, K), jnp.int32), s((q, K), jnp.bool_), s((n_pad,), jnp.bool_),
-            s((WORDS, n_pad), jnp.uint32), s((nb, WORDS), jnp.uint32),
+            s((q, k), jnp.int32), s((q, k), jnp.bool_), s((n_pad,), jnp.bool_),
+            s((words, n_pad), jnp.uint32), s((nb, words), jnp.uint32),
             s((nb,), jnp.bool_), s((1, n_pad), jnp.int8))
     return _gam_retrieve.lower(
         *args, kappa=kappa, min_overlap=min_overlap, bq=32, bn=bn,
-        words=WORDS, n_pad=n_pad, interpret=False, loop_merge=True).compile()
+        words=words, n_pad=n_pad, interpret=False, loop_merge=True).compile()
+
+
+def _kernel_calls(compiled) -> list[str]:
+    """Names of the Mosaic kernels' custom calls in the compiled program."""
+    return re.findall(r"^\s*(%\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                      compiled.as_text(), re.M)
 
 
 @pytest.mark.parametrize("n_pad,quantized,kappa,q,min_overlap", [
@@ -97,3 +106,23 @@ def test_gam_score_compiles_for_v5e(one_chip):
                                s((N, K), jnp.float32),
                                s((64, N), jnp.bool_), interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,quantized,kappa,n_pad", [
+    (100, True, 40, 1_183_744),   # glove100-int8: int8 slab, pool of 40
+    (64, False, 10, 292_864),     # lastfm64: f32 slab
+])
+def test_benchmark_cell_shapes_compile_both_loops_for_v5e(one_chip, k,
+                                                          quantized, kappa,
+                                                          n_pad):
+    """The benchmark cells' launch, 32 queries over the whole catalog,
+    with the compacted and the full overlap loop in the one program: a
+    kernel each, both named as profile readers find them
+    (``%_gam_retrieve…``)."""
+    words = -(-GamConfig(k=k, scheme="parse_tree", threshold=0.2).p // 32)
+    assert words == {100: 629, 64: 258}[k]
+    compiled = _retrieve(one_chip, n_pad=n_pad, q=32, kappa=kappa, bn=BN,
+                         quantized=quantized, min_overlap=2, k=k, words=words)
+    calls = _kernel_calls(compiled)
+    assert len(calls) == 2, calls
+    assert all(c.startswith("%_gam_retrieve") for c in calls), calls
