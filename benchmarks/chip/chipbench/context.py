@@ -19,6 +19,7 @@ class Context:
     flops_peak: float             # the peak the kernel's arithmetic runs at
     batch_work: Callable          # request indices -> (bytes, flops)
     rng: np.random.Generator      # draws the batches a reader samples
+    chips: int                    # devices the cell's kernel state is on
     n_sampled_batches: int = 64
 
     def sample_batches(self, n: int) -> np.ndarray:

@@ -48,12 +48,28 @@ def tiles_skipped_pct(ctx):
     return float(np.mean(t) * 100.0) if t else None
 
 
+def slowest_per_batch(ctx, runs, n: int | None = None):
+    """Each batch's device time on its slowest chip, ns.  ``runs`` are one
+    list per chip (``trace.program_events``, ``trace.op_events``); on a
+    cell of several chips each batch launches once on every chip, and the
+    k-th run of each chip served the k-th batch.  ``None`` unless as many
+    chips as the cell's ran, each as often (``n`` times, where given)."""
+    runs = [r for r in runs if r]
+    counts = {len(r) for r in runs}
+    if len(runs) != ctx.chips or len(counts) != 1 \
+            or (n is not None and counts != {n}):
+        return None
+    return np.max([[d for _, d in r] for r in runs], axis=0)
+
+
 def retrieve_device_ms(ctx):
-    """Device time per run of the retrieval program."""
+    """Device time per run of the retrieval program, on the slowest chip
+    of each batch."""
     if ctx.trace is None:
         return None
-    ev = tr.program_events(ctx.trace, RETRIEVE_PROGRAM)
-    return float(np.mean([d for _, d in ev]) / 1e6) if ev else None
+    d = slowest_per_batch(
+        ctx, tr.program_events(ctx.trace, RETRIEVE_PROGRAM))
+    return None if d is None else float(np.mean(d) / 1e6)
 
 
 def device_idle_pct(ctx):
@@ -71,22 +87,27 @@ def is_kernel_op(name: str) -> bool:
 
 
 def gam_retrieve_roofline(ctx):
-    """Least time the chip could take for the semantic work of a sample of
-    batches, over the kernel's device time for the same batches, in %.
+    """Least time the cell's chips could take for the semantic work of a
+    sample of batches, over the kernel's device time for the same batches,
+    in %.  The work is the whole batch's, spread over ``chips`` times one
+    chip's peaks; a batch's kernel time is its slowest chip's.
 
-    The k-th kernel run inside the window served the k-th batch answered
-    inside it; when the counts differ the reading is left out."""
+    The k-th kernel run of each chip inside the window served the k-th
+    batch answered inside it; when a chip's count differs the reading is
+    left out."""
     if ctx.trace is None:
         return None
-    runs = tr.op_events(ctx.trace, is_kernel_op)
     n = ctx.record.in_window
-    if not runs or len(runs) != n:
+    slowest = slowest_per_batch(ctx, tr.op_events(ctx.trace, is_kernel_op),
+                                n)
+    if slowest is None:
         return None
+    hbm = ctx.chips * ctx.peaks["hbm_bytes_per_s"]
+    ops = ctx.chips * ctx.flops_peak
     pick = ctx.sample_batches(n)
     t_min, t_kernel = 0.0, 0.0
     for k in pick:
         nbytes, flops = ctx.batch_work(ctx.record.batches[k])
-        t_min += max(nbytes / ctx.peaks["hbm_bytes_per_s"],
-                     flops / ctx.flops_peak)
-        t_kernel += runs[k][1] / 1e9
+        t_min += max(nbytes / hbm, flops / ops)
+        t_kernel += slowest[k] / 1e9
     return t_min / t_kernel * 100.0 if t_kernel > 0 else None

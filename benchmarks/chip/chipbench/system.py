@@ -1,9 +1,11 @@
-"""The system under test, opened as ``launch/serve.py --service`` opens it.
+"""The system under test, opened as ``launch/serve.py --service`` opens it,
+and on several chips as one process serves them (``chip_smoke.py --chips
+4``): one index over ``make_index_mesh``.
 
 This is the only module of the benchmark that imports the program: the
 ``sharded`` retriever through ``open_retriever``, its microbatcher, the
-program's own tracer for the per-layer spans, and where the program keeps
-its compiled programs.
+program's own tracer for the per-layer spans, the mesh, and where the
+program keeps its compiled programs.
 """
 from __future__ import annotations
 
@@ -17,12 +19,13 @@ sys.path.insert(0, str(ROOT / "src"))
 
 from repro.core.mapping import GamConfig  # noqa: E402
 from repro.launch import compile_cache  # noqa: E402
+from repro.launch.mesh import make_index_mesh  # noqa: E402
 from repro.obs.tracing import Tracer  # noqa: E402
 from repro.retriever import RetrieverSpec, open_retriever  # noqa: E402
 from repro.service.microbatch import QueryResult  # noqa: E402
 
-__all__ = ["QueryResult", "enable_compile_cache", "open_service",
-           "spill_rows"]
+__all__ = ["QueryResult", "cell_devices", "enable_compile_cache",
+           "kernel_placement", "open_service", "spill_rows"]
 
 
 def enable_compile_cache() -> str:
@@ -50,13 +53,38 @@ def spec_of(config: dict) -> RetrieverSpec:
         cache_capacity=int(s["cache_capacity"]))
 
 
-def open_service(config: dict, items, *, traced: bool):
-    """Build the service over ``items``; with ``traced`` the program's
-    tracer records every request batch (its ``query``, ``map``, ``base``,
-    ``delta`` and ``merge`` spans)."""
+def open_service(config: dict, items, *, traced: bool, chips: int):
+    """Build the service over ``items``; on ``chips`` > 1 its main segment
+    is partitioned over ``make_index_mesh(chips)`` (the first ``chips``
+    devices), and each batch launches the kernel once per device.  With
+    ``traced`` the program's tracer records every request batch (its
+    ``query``, ``map``, ``base``, ``delta`` and ``merge`` spans)."""
     kw = ({"tracer": Tracer(sample_rate=1.0, max_traces=1 << 20)}
           if traced else {})
+    if chips > 1:
+        kw["mesh"] = make_index_mesh(chips)
     return open_retriever(spec_of(config), items=items, **kw)
+
+
+def kernel_placement(svc) -> tuple[dict, int]:
+    """-> (the ids of the devices that hold each piece of the main
+    segment's kernel state: the factor slab, for int8 the int8 slab too,
+    and the packed patterns; the kernel launches a batch makes over it)."""
+    base = svc.base
+    meta = base.metas[0]
+    held = {"factor slab": base.factors_g[0],
+            "item_bits_t": meta.item_bits_t}
+    if meta.quantize == "int8":
+        held["int8 slab"] = meta.factors_q
+    return ({k: sorted(d.id for d in x.sharding.device_set)
+             for k, x in held.items()}, len(base._launch_units(0)))
+
+
+def cell_devices(svc, devices) -> list:
+    """The devices a cell runs on, in mesh order: the mesh's, or the first
+    device where the service has none."""
+    return list(svc.mesh.devices.flat) if svc.mesh is not None \
+        else list(devices[:1])
 
 
 def spill_rows(svc) -> int:

@@ -11,9 +11,12 @@ small recorded trace:
 * busy time: the union of the operations' intervals inside the window,
   averaged over the chips;
 * idle gaps: the complement of the busy union inside the window, each
-  named by the harness span that covers most of it, summed by name;
-* per-operation device time, summed by name;
-* per-program device time, for a program named by prefix.
+  named by the harness span that covers most of it, summed by name and
+  averaged over the chips;
+* per-operation device time, summed by name and averaged over the chips;
+* the runs of a program named by prefix, or of the operations a
+  predicate picks, on each chip: a cell on several chips runs one launch
+  per chip for each batch, and the readers pair them up by their order.
 """
 from __future__ import annotations
 
@@ -28,6 +31,8 @@ from pathlib import Path
 WINDOW = "bench.window"
 #: name of idle time during which the harness was in none of its calls
 NO_CALL = "harness between calls (no request due)"
+#: per chip, [(start_ns, dur_ns)] in time order
+Runs = list[list[tuple[float, float]]]
 
 
 @dataclasses.dataclass
@@ -143,52 +148,52 @@ def op_seconds(tr: Trace) -> list[tuple[str, float]]:
 
 def idle_gaps(tr: Trace) -> list[tuple[str, float]]:
     """Idle device seconds inside the window, by the harness span that
-    covers most of each gap, largest first (first chip)."""
+    covers most of each gap, largest first, averaged over the chips."""
     lo, hi = window(tr)
     if not tr.ops:
         return []
-    busy = union(_clip(next(iter(tr.ops.values())), lo, hi))
-    gaps, t = [], lo
-    for a, b in busy:
-        if a > t:
-            gaps.append((t, a))
-        t = max(t, b)
-    if hi > t:
-        gaps.append((t, hi))
     calls = sorted((s, s + d, n) for n, s, d in tr.host if n != WINDOW)
     starts = [c[0] for c in calls]
     tot: dict[str, float] = {}
-    for a, b in gaps:
-        best, best_ov = NO_CALL, 0.0
-        i = bisect.bisect_right(starts, b)
-        for s, e, n in reversed(calls[max(0, i - 64):i]):
-            ov = min(b, e) - max(a, s)
-            if ov > best_ov:
-                best, best_ov = n, ov
-        tot[best] = tot.get(best, 0.0) + (b - a) / 1e9
-    return sorted(tot.items(), key=lambda kv: -kv[1])
+    for evs in tr.ops.values():
+        busy = union(_clip(evs, lo, hi))
+        gaps, t = [], lo
+        for a, b in busy:
+            if a > t:
+                gaps.append((t, a))
+            t = max(t, b)
+        if hi > t:
+            gaps.append((t, hi))
+        for a, b in gaps:
+            best, best_ov = NO_CALL, 0.0
+            i = bisect.bisect_right(starts, b)
+            for s, e, n in reversed(calls[max(0, i - 64):i]):
+                ov = min(b, e) - max(a, s)
+                if ov > best_ov:
+                    best, best_ov = n, ov
+            tot[best] = tot.get(best, 0.0) + (b - a) / 1e9
+    n = len(tr.ops)
+    return sorted(((k, v / n) for k, v in tot.items()),
+                  key=lambda kv: -kv[1])
 
 
-def program_events(tr: Trace, prefix: str) -> list[tuple[float, float]]:
-    """(start_ns, dur_ns) of every run of the programs whose name starts
-    with ``prefix``, inside the window, in time order (first chip)."""
+def _runs(tr: Trace, events: dict, keep) -> Runs:
     lo, hi = window(tr)
-    if not tr.modules:
-        return []
-    evs = next(iter(tr.modules.values()))
-    return sorted((s, d) for n, s, d in evs
-                  if n.startswith(prefix) and s >= lo and s + d <= hi)
+    return [sorted((s, d) for n, s, d in evs
+                   if keep(n) and s >= lo and s + d <= hi)
+            for evs in events.values()]
 
 
-def op_events(tr: Trace, match) -> list[tuple[float, float]]:
-    """(start_ns, dur_ns) of the operations whose name satisfies
-    ``match``, inside the window, in time order (first chip)."""
-    lo, hi = window(tr)
-    if not tr.ops:
-        return []
-    evs = next(iter(tr.ops.values()))
-    return sorted((s, d) for n, s, d in evs
-                  if match(n) and s >= lo and s + d <= hi)
+def program_events(tr: Trace, prefix: str) -> Runs:
+    """Per chip: (start_ns, dur_ns) of every run of the programs whose
+    name starts with ``prefix``, inside the window, in time order."""
+    return _runs(tr, tr.modules, lambda n: n.startswith(prefix))
+
+
+def op_events(tr: Trace, match) -> Runs:
+    """Per chip: (start_ns, dur_ns) of the operations whose name
+    satisfies ``match``, inside the window, in time order."""
+    return _runs(tr, tr.ops, match)
 
 
 def save(tr: Trace, path: str | Path) -> None:

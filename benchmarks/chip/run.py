@@ -11,18 +11,30 @@ for.  The cell, its configuration and its traffic are found by name
 
 Set-up (``setup_s``): draw the catalog and the window's queries on the
 device from the seed, build the service, refuse a catalog with spill rows,
-and send warm-up batches through every shape the window uses.  The window
-then offers the cell's traffic for ``--seconds``.  With ``--trace 0`` the
-result carries the cell's end-to-end metrics; with ``--trace 1`` the
-program's tracer and the profiler are on, and it carries the per-layer
-metrics, the device's busy and window seconds and a breakdown.  After the
-window the peak device memory is read, the service is freed, and a sample
-of the window's answers is compared with the plain reference.
+and send warm-up batches through every shape the window uses.  A cell of
+one chip serves from the first device.  A cell whose ``chips`` is more
+opens the service on ``make_index_mesh(chips)``, as one process serves a
+host's chips: the main segment's kernel state is partitioned over the
+devices and each batch launches the kernel once on each.  The run is
+refused unless the factor slab (and an int8 slab), the packed patterns and
+the launches a batch makes are each spread over exactly the cell's chips.
+The window then offers the cell's traffic for ``--seconds``.  With
+``--trace 0`` the result carries the cell's end-to-end metrics; with
+``--trace 1`` the program's tracer and the profiler are on, and it carries
+the per-layer metrics, the device's busy and window seconds and a
+breakdown; a batch's kernel time is its slowest chip's.  After the window
+the peak memory of each of the cell's devices is read (the fullest is
+``memory_peak_bytes``), with the bytes each holds at the window's close
+(its share of the served state: a mesh's build stages on the first
+device, whose peak is the set-up's), the service is freed, and a sample
+of the window's answers is compared with the plain reference.  ``setup`` in the result
+breaks set-up into drawing the data, the build, the warm-up, and the
+check's own time after the window; those are reported, not bounded.
 
 The last line of stdout is one JSON object; the numbers compared are the
-last lines of stderr and the last key of that object.  Without a TPU, or
-with fewer chips than the cell asks for, the run prints no result and
-exits 1.
+last lines of stderr and the last key of that object.  Without a TPU, with
+fewer chips than the cell asks for, or with a catalog set-up refuses, the
+run prints no result and exits 1.
 """
 from __future__ import annotations
 
@@ -106,6 +118,27 @@ def warm_up(svc, rows, result_type) -> None:
             raise RuntimeError("a warm-up request was not answered")
 
 
+def placement(svc, chips: int) -> tuple[int, str | None]:
+    """-> (the devices that hold the main segment's kernel state, why the
+    run is refused or None).  It is refused unless the factor slab, an
+    int8 slab and the packed patterns each lie on the same ``chips``
+    devices and a batch launches the kernel once on each."""
+    from chipbench import system
+
+    held, launches = system.kernel_placement(svc)
+    print(f"setup: kernel state on devices {held}, {launches} launch(es) "
+          f"a batch", file=sys.stderr, flush=True)
+    used = len(set().union(*held.values()))
+    if launches == chips and all(
+            len(ids) == chips and ids == held["factor slab"]
+            for ids in held.values()):
+        return used, None
+    return used, (
+        f"kernel state is not spread over the {chips} chip(s) the cell "
+        f"asks for: {', '.join(f'{k} on {len(v)}' for k, v in held.items())}"
+        f" device(s), {launches} launch(es) a batch; refusing to run")
+
+
 def main(argv=None) -> int:
     args = parse(argv)
     cell = load_cell(args.workload)
@@ -170,7 +203,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, env, *,
     items, rows = data.make_rows(seed, cfg, sched.n_rows + n_warm)
     warm_rows, queries = rows[:n_warm], rows[n_warm:]
     t_data = time.perf_counter()
-    svc = system.open_service(cfg, items, traced=traced)
+    svc = system.open_service(cfg, items, traced=traced, chips=cell.chips)
     t_build = time.perf_counter()
     spill = system.spill_rows(svc)
     print(f"setup: {cfg['n_items']} items x {cfg['dim']}, bucket "
@@ -184,9 +217,14 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, env, *,
         return None, None
     if fault is not None:
         fault(svc)
+    chips_used, refusal = placement(svc, cell.chips)
+    if refusal:
+        fail(refusal)
+        return None, None
     warm_up(svc, warm_rows, system.QueryResult)
-    setup_s = time.perf_counter() - t_setup
-    print(f"setup: warm-up {time.perf_counter() - t_build:.2f}s, "
+    t_warm = time.perf_counter()
+    setup_s = t_warm - t_setup
+    print(f"setup: warm-up {t_warm - t_build:.2f}s, "
           f"setup_s {setup_s:.2f}", file=sys.stderr, flush=True)
     n_compiled_setup = compiles.n
 
@@ -205,8 +243,11 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, env, *,
     if traced:
         jax.profiler.stop_trace()
         tr = trace_mod.load(trace_mod.find_xplane(trace_dir))
-    stats = devices[0].memory_stats() or {}
-    memory_peak = int(stats.get("peak_bytes_in_use", 0))
+    # the peak counts the build's staging on the first device; what each
+    # device holds at the window's close is its share of the served state
+    mem = [d.memory_stats() or {} for d in system.cell_devices(svc, devices)]
+    peaks_per_device = [int(m.get("peak_bytes_in_use", 0)) for m in mem]
+    in_use_per_device = [int(m.get("bytes_in_use", 0)) for m in mem]
     spans = list(svc.tracer.finished) if traced else []
     print(f"window: {rec.issued} requests in {rec.window_s:.3f}s, "
           f"{len(rec.batches)} batches, programs lowered in set-up "
@@ -216,6 +257,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, env, *,
     gc.collect()
 
     # -------------------------------------------- the check (reference)
+    t_check = time.perf_counter()
     issued = rec.issued
     lost = int(issued - rec.answered[:issued].sum())
     refc = check.Reference(items, cfg)
@@ -226,6 +268,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, env, *,
     gap = float(gap)
     limit = float(cfg["check"]["answer_gap_limit"])
     correct = lost == 0 and gap <= limit
+    check_s = time.perf_counter() - t_check
 
     # -------------------------------------------- metrics
     metrics = {}
@@ -257,7 +300,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, env, *,
         ctx = Context(cfg, sched, rec, spans, tr, peaks,
                       peaks["int8_ops_per_s"] if int8
                       else peaks["bf16_flops_per_s"],
-                      batch_work, data.host_rng(seed, 3))
+                      batch_work, data.host_rng(seed, 3), cell.chips)
         for m in cell.per_layer:
             v = load_reader(m["name"])(ctx)
             if v is not None:
@@ -265,7 +308,10 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, env, *,
 
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
-              "memory_peak_bytes": memory_peak}
+              "memory_peak_bytes": max(peaks_per_device),
+              "memory_peak_bytes_per_device": peaks_per_device,
+              "memory_in_use_bytes_per_device": in_use_per_device,
+              "chips_used": chips_used}
     out = {"correct": bool(correct), "attempted": int(issued),
            "failed": lost, "metrics": metrics, "device": device}
     if traced:
@@ -277,7 +323,9 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, env, *,
     if not traced:
         # the whole tail, for the record: the bounded metrics are above
         out["latency_ms"] = {f"p{p:g}": v for p, v in pct.items()}
-    out["setup"] = {"setup_s": setup_s, "spill_rows": spill,
+    out["setup"] = {"setup_s": setup_s, "data_s": t_data - t_setup,
+                    "build_s": t_build - t_data, "warm_s": t_warm - t_build,
+                    "check_s": check_s, "spill_rows": spill,
                     "programs_lowered_in_window": n_compiled_window,
                     "queries_left_out_ambiguous": n_amb,
                     "answers_compared": int(pick.size)}

@@ -3,7 +3,8 @@
     python3 benchmarks/chip/sweep.py --workload <name> --seed <n> \\
         --seconds 5 --rates 500 1000 2000 ...
 
-One set-up of the cell's configuration, then one window per offered rate
+One set-up of the cell's configuration, on the cell's chips as ``run.py``
+places it (and refused as it refuses), then one window per offered rate
 (Poisson, fresh queries), in the order given.  Per rate: requests, the
 answered rate, p50 and p99 latency from the due time, and the p99 of the
 last fifth of the window against the first fifth, which grows when the
@@ -22,18 +23,20 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
 import run  # noqa: E402
-from chipbench.cell import load_cell  # noqa: E402
+from chipbench.cell import ROOT, load_cell  # noqa: E402
 
 
-def main(argv=None) -> int:
+def main(argv=None, *, root: Path = ROOT, require_chip: bool = True) -> int:
+    """``root`` and ``require_chip`` are for the benchmark's own tests, as
+    ``run.open_device``'s are."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--seconds", type=float, default=5.0)
     ap.add_argument("--rates", type=float, nargs="+", required=True)
     args = ap.parse_args(argv)
-    cell = load_cell(args.workload)
-    env = run.open_device(cell)
+    cell = load_cell(args.workload, root)
+    env = run.open_device(cell, require_chip)
     if isinstance(env, str):
         return run.fail(env)
     import numpy as np
@@ -49,7 +52,10 @@ def main(argv=None) -> int:
     n_warm = (run.WARM_BATCHES + 1) * bs
     items, rows = data.make_rows(args.seed, cfg,
                                  n_warm + sum(s.n_rows for s in scheds))
-    svc = system.open_service(cfg, items, traced=False)
+    svc = system.open_service(cfg, items, traced=False, chips=cell.chips)
+    _, refusal = run.placement(svc, cell.chips)
+    if refusal:
+        return run.fail(refusal)
     run.warm_up(svc, rows[:n_warm], system.QueryResult)
     off = n_warm
     for rate, sched in zip(args.rates, scheds):

@@ -7,7 +7,9 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from chipbench import program_spans, readers
 from chipbench import trace as tr
+from chipbench.context import Context
 
 DATA = Path(__file__).parent / "data" / "trace_lastfm64_60ms.json"
 
@@ -32,7 +34,8 @@ def timeline(events, lo, hi, step_ns=100.0):
 def test_the_recorded_trace_has_what_the_readers_use(rec):
     assert list(rec.ops) == ["/device:TPU:0"]
     assert tr.window_s(rec) == pytest.approx(0.06)
-    assert tr.program_events(rec, "jit__gam_retrieve")
+    [runs] = tr.program_events(rec, "jit__gam_retrieve")
+    assert runs
     assert any(n.startswith("bench.submit") for n, _, _ in rec.host)
 
 
@@ -83,10 +86,131 @@ def test_short_names():
 
 
 def test_each_program_run_holds_one_kernel_run(rec):
-    from chipbench import readers
-
     kernels = tr.op_events(rec, readers.is_kernel_op)
     programs = tr.program_events(rec, readers.RETRIEVE_PROGRAM)
-    assert len(kernels) == len(programs) > 0
-    for (ks, kd), (ps, pd) in zip(kernels, programs):
-        assert ps <= ks and ks + kd <= ps + pd
+    assert len(kernels) == len(programs) == len(rec.ops)
+    for chip_k, chip_p in zip(kernels, programs):
+        assert len(chip_k) == len(chip_p) > 0
+        for (ks, kd), (ps, pd) in zip(chip_k, chip_p):
+            assert ps <= ks and ks + kd <= ps + pd
+
+
+class Record:
+    """The part of ``drive.Record`` the trace readers use."""
+
+    def __init__(self, n_batches: int, batch_size: int = 32):
+        self.in_window = n_batches
+        self.batches = [np.arange(k * batch_size, (k + 1) * batch_size)
+                        for k in range(n_batches)]
+
+
+PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
+
+
+def batch_work(reqs):
+    """A made-up amount of work per batch, different for each batch."""
+    return float(reqs[0] + 1) * 1e6, float(reqs[0] + 7) * 3e9
+
+
+def context(trace, n_batches, chips):
+    return Context({}, None, Record(n_batches), [], trace, PEAKS,
+                   PEAKS["bf16_flops_per_s"], batch_work,
+                   np.random.default_rng(5), chips)
+
+
+def recorded_query_spans(rec):
+    """Host ``repro.query`` events around each program run, 0.1 ms wider
+    on either side: the recorded trace holds none of the program's own."""
+    [runs] = tr.program_events(rec, readers.RETRIEVE_PROGRAM)
+    return program_spans.ProgramSpans.of(
+        rec, [("repro.query", s - 1e5, d + 2e5) for s, d in runs])
+
+
+#: every reader of the device trace on the recorded one-chip trace, as the
+#: harness gave it before it read several chips (exact, to the last bit)
+ONE_CHIP_READINGS = {
+    "busy_s": (lambda rec: tr.busy_s(rec), 0.025724369),
+    "window_s": (lambda rec: tr.window_s(rec), 0.06),
+    "op_seconds": (lambda rec: tr.op_seconds(rec)[:3], [
+        ("%_gam_retrieve.1 f32[32,10]", 0.023585507),
+        ("%copy.11 f32[292864,64]", 0.0017486029999999998),
+        ("%fusion u32[8256]", 9.058099999999999e-05)]),
+    "idle_gaps": (lambda rec: tr.idle_gaps(rec), [
+        ("bench.submit", 0.027392579999999934),
+        ("harness between calls (no request due)", 0.0068830510000000115)]),
+    "retrieve_device_ms": (
+        lambda rec: readers.retrieve_device_ms(context(rec, 4, 1)),
+        6.2232905),
+    "gam_retrieve_roofline": (
+        lambda rec: readers.gam_retrieve_roofline(context(rec, 4, 1)),
+        14.385546494091825),
+    "device_idle_pct": (
+        lambda rec: readers.device_idle_pct(context(rec, 4, 1)),
+        57.12605166666667),
+    "idle_in_query_pct": (
+        lambda rec: recorded_query_spans(rec).idle_in_query_pct(),
+        1.3353866666666667),
+}
+
+
+@pytest.mark.parametrize("reader", sorted(ONE_CHIP_READINGS))
+def test_one_chip_readings_are_unchanged(rec, reader):
+    read, value = ONE_CHIP_READINGS[reader]
+    assert read(rec) == value
+
+
+#: kernel and program durations (ns) of three batches on four chips, each
+#: batch's slowest on another chip
+KERNEL_NS = [[9.0e6, 9.5e6, 9.2e6],
+             [9.1e6, 9.0e6, 9.9e6],
+             [9.8e6, 9.1e6, 9.0e6],
+             [9.2e6, 9.3e6, 9.1e6]]
+PROGRAM_EXTRA_NS = 3e5
+KERNEL_OP = ('%_gam_retrieve.1 = (f32[32,10]{1,0}, s32[32,10]{1,0}) '
+             'custom-call(...), custom_call_target="tpu_custom_call"')
+
+
+def four_chip_trace(drop=None):
+    """Three batches, each launched once on every chip 20 ms apart, the
+    chips' planes in no order; ``drop`` = (chip, batch) leaves that run
+    out."""
+    ops, modules = {}, {}
+    for c in (2, 0, 3, 1):
+        plane = f"/device:TPU:{c}"
+        ops[plane], modules[plane] = [], []
+        for k, d in enumerate(KERNEL_NS[c]):
+            if (c, k) == drop:
+                continue
+            start = 1e6 + k * 2e7 + c * 1e4
+            modules[plane].append(("jit__gam_retrieve(1)", start,
+                                   d + PROGRAM_EXTRA_NS))
+            ops[plane] += [("%copy.1 = f32[8]{0} copy(x)", start, 1e5),
+                           (KERNEL_OP, start + 1e5, d)]
+    return tr.Trace(ops, modules, [("bench.window", 0.0, 7e7),
+                                   ("bench.submit", 5e5, 2e7)])
+
+
+def test_four_chips_read_the_slowest_chip_of_each_batch():
+    t = four_chip_trace()
+    slowest = np.max(KERNEL_NS, axis=0)
+    runs = tr.op_events(t, readers.is_kernel_op)
+    assert sorted([d for _, d in chip] for chip in runs) == sorted(KERNEL_NS)
+    ctx = context(t, 3, 4)
+    assert readers.retrieve_device_ms(ctx) == pytest.approx(
+        np.mean(slowest + PROGRAM_EXTRA_NS) / 1e6, rel=1e-12)
+    t_min = sum(max(b / (4 * PEAKS["hbm_bytes_per_s"]),
+                    f / (4 * PEAKS["bf16_flops_per_s"]))
+                for b, f in map(batch_work, ctx.record.batches))
+    assert readers.gam_retrieve_roofline(ctx) == pytest.approx(
+        t_min / (slowest.sum() / 1e9) * 100.0, rel=1e-12)
+    # a cell of one chip finds four chips' launches and reads nothing
+    assert readers.gam_retrieve_roofline(context(t, 3, 1)) is None
+    # idle time is averaged over the chips, as busy time is
+    gaps = sum(s for _, s in tr.idle_gaps(t))
+    assert gaps == pytest.approx(tr.window_s(t) - tr.busy_s(t), rel=1e-12)
+
+
+def test_a_chip_with_a_missing_run_leaves_the_kernel_readings_out():
+    ctx = context(four_chip_trace(drop=(2, 1)), 3, 4)
+    assert readers.gam_retrieve_roofline(ctx) is None
+    assert readers.retrieve_device_ms(ctx) is None
