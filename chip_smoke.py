@@ -217,7 +217,7 @@ def on_one_device(base, device):
              for f in dataclasses.fields(meta)
              if isinstance(getattr(meta, f.name), jax.Array)}
     return ShardedGamIndex(
-        base.cfg, base.item_ids, base.tables, base.counts, base.spills,
+        base.cfg, base.item_ids, base.postings, base.counts, base.spills,
         jax.device_put(base.factors_g[0], device),
         np.asarray(base.alive_g[0]), base.partition, base.min_overlap,
         base.bucket, None, [dataclasses.replace(meta, **moved)],
@@ -278,7 +278,7 @@ def four_chip_phase(*, n_items: int, n_requests: int, seed: int,
     for label in ("f32", "int8"):
         if label == "int8":
             base = ShardedGamIndex(
-                base.cfg, base.item_ids, base.tables, base.counts,
+                base.cfg, base.item_ids, base.postings, base.counts,
                 base.spills, base.factors_g[0], np.asarray(base.alive_g[0]),
                 base.partition, base.min_overlap, base.bucket, mesh,
                 list(base.metas), quantize="int8",

@@ -35,8 +35,8 @@ from repro.compress.postings import (CodecError, CompressedPostings,
                                      decode_postings, encode_postings)
 
 __all__ = ["CompressedInvertedIndex", "InvertedIndex", "DeviceIndex",
-           "build_segment", "candidate_mask_from_table", "csr_to_table",
-           "table_to_csr"]
+           "build_segment", "build_segment_csr", "candidate_mask_from_table",
+           "csr_to_table", "table_to_csr"]
 
 
 def table_to_csr(table: np.ndarray, counts: np.ndarray
@@ -91,6 +91,30 @@ def candidate_mask_from_table(table: jax.Array, spill: jax.Array,
     return mask.at[spill].set(True, mode="drop")
 
 
+def _sorted_postings(item_indices: np.ndarray, p: int,
+                     mask: np.ndarray | None):
+    """Every (slot, item) posting of a segment sorted by slot, items
+    ascending within a slot: -> (slots, items, position within the slot's
+    list, per-slot list lengths)."""
+    item_indices = np.asarray(item_indices)
+    n, k = item_indices.shape
+    if mask is None:
+        mask = np.ones((n, k), bool)
+    mask = np.asarray(mask, bool)
+    flat_slots = item_indices[mask].astype(np.int64)
+    flat_items = np.broadcast_to(
+        np.arange(n, dtype=np.int32)[:, None], (n, k)
+    )[mask]
+    order = np.argsort(flat_slots, kind="stable")
+    slots_sorted = flat_slots[order]
+    items_sorted = flat_items[order]
+    counts_full = np.bincount(slots_sorted, minlength=p)
+    starts = np.zeros(p, np.int64)
+    np.cumsum(counts_full[:-1], out=starts[1:])
+    pos = np.arange(slots_sorted.size, dtype=np.int64) - starts[slots_sorted]
+    return slots_sorted, items_sorted, pos, counts_full
+
+
 def build_segment(item_indices: np.ndarray, p: int, bucket: int,
                   mask: np.ndarray | None = None, sentinel: int | None = None,
                   ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -107,30 +131,34 @@ def build_segment(item_indices: np.ndarray, p: int, bucket: int,
     the sequential per-item build, but O(nnz log nnz) numpy instead of an
     O(N*k) Python loop (this is the hot path of ``compact()``).
     """
-    item_indices = np.asarray(item_indices)
-    n, k = item_indices.shape
     if sentinel is None:
-        sentinel = n
-    if mask is None:
-        mask = np.ones((n, k), bool)
-    mask = np.asarray(mask, bool)
-    flat_slots = item_indices[mask].astype(np.int64)
-    flat_items = np.broadcast_to(
-        np.arange(n, dtype=np.int32)[:, None], (n, k)
-    )[mask]
-    order = np.argsort(flat_slots, kind="stable")
-    slots_sorted = flat_slots[order]
-    items_sorted = flat_items[order]
-    counts_full = np.bincount(slots_sorted, minlength=p)
-    starts = np.zeros(p, np.int64)
-    np.cumsum(counts_full[:-1], out=starts[1:])
-    pos = np.arange(slots_sorted.size, dtype=np.int64) - starts[slots_sorted]
+        sentinel = np.asarray(item_indices).shape[0]
+    slots_sorted, items_sorted, pos, counts_full = _sorted_postings(
+        item_indices, p, mask)
     table = np.full((p, bucket), sentinel, dtype=np.int32)
     fit = pos < bucket
     table[slots_sorted[fit], pos[fit]] = items_sorted[fit]
     spill = np.unique(items_sorted[~fit]).astype(np.int32)
     counts = np.minimum(counts_full, bucket).astype(np.int32)
     return table, counts, spill
+
+
+def build_segment_csr(item_indices: np.ndarray, p: int, bucket: int,
+                      mask: np.ndarray | None = None):
+    """:func:`build_segment`'s segment in CSR form, without the dense
+    (p, bucket) table: -> ``(postings, offsets, counts, spill)``, slot
+    ``i``'s list (clipped to ``bucket``) being
+    ``postings[offsets[i]:offsets[i + 1]]``.  ``csr_to_table(postings,
+    offsets, bucket, sentinel)`` densifies it into ``build_segment``'s
+    table bit-for-bit."""
+    _, items_sorted, pos, counts_full = _sorted_postings(item_indices, p,
+                                                         mask)
+    fit = pos < bucket
+    counts = np.minimum(counts_full, bucket).astype(np.int32)
+    offsets = np.zeros(p + 1, np.int64)
+    np.cumsum(counts, out=offsets[1:])
+    spill = np.unique(items_sorted[~fit]).astype(np.int32)
+    return items_sorted[fit], offsets, counts, spill
 
 
 class InvertedIndex:

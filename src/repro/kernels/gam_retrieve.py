@@ -70,8 +70,9 @@ from repro.kernels.gam_score import NEG
 
 __all__ = ["RetrievalMeta", "GamRetrieveResult", "RowCapacityError",
            "COMPACT_WORDS", "SKIP_MAP_TILES", "TOPK_EMPTY_ROW",
-           "build_retrieval_meta", "effective_bq", "expand_tile_skips",
-           "export_topk", "gam_retrieve", "pack_patterns", "rerank_pool"]
+           "block_unions", "build_retrieval_meta", "effective_bq",
+           "expand_tile_skips", "export_topk", "gam_retrieve", "pack_patterns",
+           "rerank_pool"]
 
 # Row sentinel for non-candidate tile entries: larger than any real global row
 # (catalogs < 2^30 rows — enforced by RowCapacityError at build/assembly
@@ -187,17 +188,39 @@ class RetrievalMeta:
         return self.n_pad // self.bn
 
 
-def pack_patterns(tau: np.ndarray, mask: np.ndarray, p: int) -> np.ndarray:
-    """(n, k) tau destinations + non-zero mask -> (n, ceil(p/32)) uint32 bitsets."""
+#: rows :func:`pack_patterns` scatters at a time
+_PACK_ROWS = 1 << 16
+
+
+def pack_patterns(tau: np.ndarray, mask: np.ndarray, p: int,
+                  out: np.ndarray | None = None, col: int = 0) -> np.ndarray:
+    """(n, k) tau destinations + non-zero mask -> (ceil(p/32), n) uint32
+    bitsets, packed straight into the kernel's ``item_bits_t`` layout (one
+    column per row): row ``i`` lands in column ``col + i`` of ``out`` (a
+    C-contiguous array, zeros where nothing is packed yet).  Rows go
+    ``_PACK_ROWS`` at a time, so the scatter's temporaries stay small
+    whatever ``n`` is, and runs of disjoint columns may be packed from
+    several threads."""
     tau = np.asarray(tau)
     mask = np.asarray(mask, bool)
-    n, _ = tau.shape
-    words = -(-p // 32)
-    bits = np.zeros((n, words), np.uint32)
-    rows = np.broadcast_to(np.arange(n)[:, None], tau.shape)
-    vals = np.uint32(1) << (tau % 32).astype(np.uint32)
-    np.bitwise_or.at(bits, (rows[mask], (tau // 32)[mask]), vals[mask])
-    return bits
+    n = tau.shape[0]
+    if out is None:
+        out = np.zeros((-(-p // 32), n), np.uint32)
+    flat, width = out.reshape(-1), out.shape[1]
+    for lo in range(0, n, _PACK_ROWS):
+        r, j = np.nonzero(mask[lo:lo + _PACK_ROWS])
+        t = tau[lo:lo + _PACK_ROWS][r, j].astype(np.int64)
+        np.bitwise_or.at(flat, (t >> 5) * width + (col + lo) + r,
+                         np.uint32(1) << (t & 31).astype(np.uint32))
+    return out
+
+
+def block_unions(bits_t: np.ndarray, bn: int) -> np.ndarray:
+    """(words, n) packed patterns, n a multiple of ``bn`` -> the
+    (n / bn, words) OR of each ``bn``-row block's patterns."""
+    words = bits_t.shape[0]
+    return np.ascontiguousarray(np.bitwise_or.reduce(
+        bits_t.reshape(words, -1, bn), axis=2).T)
 
 
 def _pack_patterns_jnp(tau: jax.Array, mask: jax.Array, words: int) -> jax.Array:
@@ -259,16 +282,15 @@ def build_retrieval_meta(tau: np.ndarray, mask: np.ndarray, p: int, *,
     n_pad = n_blocks * bn
     if n_pad > ROW_CAPACITY:     # before any O(n_pad) allocation
         raise RowCapacityError("padded catalog (n_pad)", n_pad)
-    bits = np.zeros((n_pad, words), np.uint32)
+    bits_t = np.zeros((words, n_pad), np.uint32)
     if n:
-        bits[:n] = pack_patterns(tau, mask, p)
+        pack_patterns(tau, mask, p, out=bits_t)
     spill = np.zeros(n_pad, bool)
     if spill_rows is not None and np.asarray(spill_rows).size:
         spill[np.asarray(spill_rows, np.int64)] = True
-    union = np.bitwise_or.reduce(bits.reshape(n_blocks, bn, words), axis=1)
     meta = RetrievalMeta(
-        item_bits_t=jnp.asarray(np.ascontiguousarray(bits.T)),
-        block_union=jnp.asarray(union),
+        item_bits_t=jnp.asarray(bits_t),
+        block_union=jnp.asarray(block_unions(bits_t, bn)),
         block_spill=jnp.asarray(spill.reshape(n_blocks, bn).any(axis=1)),
         spill8=jnp.asarray(spill.astype(np.int8)[None, :]),
         p=int(p), words=words, bn=bn, n_rows=n_rows, n_pad=n_pad,

@@ -116,7 +116,7 @@ def _slice_index(g: ShardedGamIndex, placement: HostPlacement,
             else jnp.concatenate(factor_parts))
     return ShardedGamIndex(
         g.cfg, g.item_ids[cat_lo:cat_lo + sub_part.n],
-        g.tables[s_lo:s_hi], g.counts[s_lo:s_hi], g.spills[s_lo:s_hi],
+        g.postings[s_lo:s_hi], g.counts[s_lo:s_hi], g.spills[s_lo:s_hi],
         flat, g._alive_host[row_lo:row_lo + sub_part.n_rows],
         sub_part, g.min_overlap, g.bucket, None, metas,
         quantize=g.quantize, rerank_factor=g.rerank_factor)
@@ -213,8 +213,8 @@ class MultiHostIndex:
 
     # snapshot proxies (parent payload reads these off ``self.base``)
     @property
-    def tables(self):
-        return self.global_index.tables
+    def postings(self):
+        return self.global_index.postings
 
     @property
     def counts(self):

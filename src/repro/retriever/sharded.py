@@ -48,7 +48,7 @@ import numpy as np
 
 from repro.compress.postings import CompressedPostings, decode_postings, \
     encode_postings
-from repro.core.inverted_index import csr_to_table, table_to_csr
+from repro.core.inverted_index import table_to_csr
 from repro.core.mapping import sparse_map
 from repro.kernels.gam_retrieve import RetrievalMeta
 from repro.kernels.gam_score import NEG
@@ -139,7 +139,7 @@ class ShardedRetriever(Retriever):
             n_shards=self.spec.n_shards, min_overlap=self.spec.min_overlap,
             bucket=self.spec.bucket, mesh=self.mesh, partition=partition,
             premapped=premapped, quantize=self.spec.quantize,
-            rerank_factor=self.spec.rerank_factor)
+            rerank_factor=self.spec.rerank_factor, tracer=self.tracer)
 
     def _adopt_base(self, base) -> None:
         """Install a freshly built main segment (the swap point shared by
@@ -162,13 +162,17 @@ class ShardedRetriever(Retriever):
         items = np.asarray(items, np.float32).reshape(-1, self.spec.cfg.k)
         ids = (np.arange(items.shape[0], dtype=np.int64) if ids is None
                else np.asarray(ids, np.int64).ravel())
-        if len(np.unique(ids)) != ids.size:
+        if ids.size > 1 and not (ids[1:] > ids[:-1]).all() \
+                and len(np.unique(ids)) != ids.size:
             raise ValueError("item ids must be unique")
         self._planner = None           # a full build supersedes any in-flight
         self._rebalanced = False
         self.catalog = {int(i): f for i, f in zip(ids, items)}
         self._map_cache.clear()
         self._bump_cache()
+        # the superseded segment's host and device memory is freed before
+        # the new one is built, not held beside it
+        self.base = None
         self.base = self._build_base(items, ids)
         self.delta.clear()
         return self
@@ -772,21 +776,19 @@ class ShardedRetriever(Retriever):
                             "partition": {"lengths": list(part.lengths),
                                           "bns": list(part.bns),
                                           "caps": list(part.caps)}}
+        # the shards' CSR posting segments as one stream over the S * p
+        # slots (its offsets are base_counts' running sum), varint-encoded
+        # under compress_postings; restore splits it back shard by shard
+        post = np.concatenate([pl for pl, _ in base.postings])
+        off = np.zeros(base.counts.size + 1, np.int64)
+        np.cumsum(np.ravel(base.counts), out=off[1:])
         if self.spec.compress_postings:
-            # the (S, p, bucket) dense-bucket tables flattened to one CSR
-            # stream (the per-slot counts are already persisted as
-            # base_counts); restore re-densifies shard by shard against
-            # each shard's own pad sentinel, bit-identically
-            tables = np.asarray(base.tables)
-            counts = np.asarray(base.counts).astype(np.int64)
-            post, off = table_to_csr(
-                tables.reshape(-1, tables.shape[-1]), counts.ravel())
             cp = encode_postings(post, off)
             arrays["base_tables_data"] = cp.data
             extra_base["codec"] = {"n_values": int(cp.n_values),
-                                   "bucket": int(tables.shape[-1])}
+                                   "bucket": int(base.bucket)}
         else:
-            arrays["base_tables"] = base.tables
+            arrays["base_postings"] = post
         per_group = []
         for g, meta in enumerate(base.metas):
             arrays[f"meta{g}_item_bits_t"] = meta.item_bits_t
@@ -837,29 +839,28 @@ class ShardedRetriever(Retriever):
                                        jnp.float32))
             metas.append(meta)
         counts = np.asarray(arrays["base_counts"])
-        if "base_tables_data" in arrays:
-            codec = b["codec"]
-            cp = CompressedPostings(
-                np.asarray(arrays["base_tables_data"], np.uint8),
-                counts.ravel().astype(np.int32), int(codec["n_values"]))
-            post, off = decode_postings(cp)
-            bucket = int(codec["bucket"])
-            p = self.spec.cfg.p
-            shard_tables = []
-            for s in range(part.n_shards):
-                lo, hi = off[s * p], off[(s + 1) * p]
-                soff = off[s * p:(s + 1) * p + 1] - lo
-                tab, _ = csr_to_table(post[lo:hi], soff, bucket,
-                                      sentinel=part.caps[s])
-                shard_tables.append(tab)
-            tables = np.stack(shard_tables)
-        else:
+        if "base_tables" in arrays:    # files written before the CSR stream
             tables = np.asarray(arrays["base_tables"])
+            post, off = table_to_csr(
+                tables.reshape(-1, tables.shape[-1]), counts.ravel())
+        elif "base_tables_data" in arrays:
+            post, off = decode_postings(CompressedPostings(
+                np.asarray(arrays["base_tables_data"], np.uint8),
+                counts.ravel().astype(np.int32),
+                int(b["codec"]["n_values"])))
+        else:
+            post = np.asarray(arrays["base_postings"])
+            off = np.zeros(counts.size + 1, np.int64)
+            np.cumsum(counts.ravel(), out=off[1:])
+        p = self.spec.cfg.p
+        postings = []
+        for s in range(part.n_shards):
+            lo, hi = off[s * p], off[(s + 1) * p]
+            postings.append((post[lo:hi].astype(np.int32),
+                             off[s * p:(s + 1) * p + 1] - lo))
         self._adopt_base(ShardedGamIndex(
             self.spec.cfg, np.asarray(arrays["base_item_ids"], np.int64),
-            jnp.asarray(tables),
-            jnp.asarray(counts),
-            jnp.asarray(arrays["base_spills"]),
+            postings, counts, np.asarray(arrays["base_spills"]),
             jnp.asarray(arrays["base_factors"]),
             np.asarray(arrays["base_alive"], bool),
             part, self.spec.min_overlap, int(b["bucket"]), None, metas,

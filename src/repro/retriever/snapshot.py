@@ -19,14 +19,14 @@ from repro.retriever.api import RetrieverSpec
 __all__ = ["read_snapshot", "write_snapshot"]
 
 # v4: the compressed-catalog formats — varint-encoded posting tables
-# (``compress_postings``, storage-only: the reader re-densifies
-# bit-identically, keyed on which arrays are present) and int8 factor slabs
-# with per-block scales (``quantize``/``rerank_factor``, result-bearing:
-# within-backend bitwise score identity pins the scoring path).  v3 (adds
-# the optional multi-host placement) and v2 files read unchanged — their
-# headers predate the new spec fields, so readers fill the uncompressed
-# defaults.  v1 files are still rejected loudly here rather than
-# KeyError-ing mid-restore.
+# (``compress_postings``, storage-only: the reader decodes the same CSR
+# stream bit-identically, keyed on which arrays are present) and int8
+# factor slabs with per-block scales (``quantize``/``rerank_factor``,
+# result-bearing: within-backend bitwise score identity pins the scoring
+# path).  v3 (adds the optional multi-host placement) and v2 files read
+# unchanged — their headers predate the new spec fields, so readers fill
+# the uncompressed defaults.  v1 files are still rejected loudly here
+# rather than KeyError-ing mid-restore.
 SNAPSHOT_FORMAT = "repro.retriever/v4"
 _READ_COMPAT = (SNAPSHOT_FORMAT, "repro.retriever/v3", "repro.retriever/v2")
 

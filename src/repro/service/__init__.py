@@ -38,13 +38,14 @@ Components
 ``ShardedGamIndex`` (``sharded_index.py``)
     The compacted main segment.  The id-sorted catalog is cut into
     contiguous shards; each shard owns a dense-bucket posting segment
-    (built by the vectorised ``core.inverted_index.build_segment``) over
-    local rows.  Queries stream the flat factor matrix through the fused
-    ``kernels.gam_retrieve`` kernel, whose item axis ``sharding.specs
-    .index_shardings`` partitions over ``launch.mesh.make_index_mesh`` —
-    catalog size scales with devices.  ``kill()`` tombstones rows AND
-    refreshes the kernel's block-union/spill metadata, so long tombstone
-    streams cannot erode the zero-candidate block-skip rate.
+    (built by the vectorised ``core.inverted_index.build_segment_csr``,
+    held in host memory) over local rows.  Queries stream the flat factor
+    matrix through the fused ``kernels.gam_retrieve`` kernel, whose item
+    axis a ``launch.mesh.make_index_mesh`` mesh splits block-aligned, each
+    device's share built on it — catalog size scales with devices.
+    ``kill()`` tombstones rows AND refreshes the kernel's block-union/spill
+    metadata, so long tombstone streams cannot erode the zero-candidate
+    block-skip rate.
 
 ``DeltaSegment`` (``delta.py``)
     Streaming ``upsert``/``delete`` land in a small dense segment that every

@@ -219,7 +219,7 @@ class CompactionPlanner:
                 g = len(self._metas)
                 self._metas.append(build_group_meta(
                     self._tau, self._mask, self.cfg.p, self.partition, g,
-                    [sp for _, _, sp in self._segs]))
+                    [sp for _, _, sp in self._segs], mesh=self.mesh))
                 if len(self._metas) < len(self.partition.groups):
                     return self.phase
             self.phase = "finalize"

@@ -18,7 +18,7 @@ from repro.configs.base import ModelConfig
 from repro.launch.mesh import data_axes
 
 __all__ = ["param_specs", "param_shardings", "batch_specs", "cache_specs",
-           "named", "index_shardings"]
+           "named"]
 
 # (path regex, spec builder taking ndim) — first match wins.
 _RULES: list[tuple[str, object]] = [
@@ -149,20 +149,6 @@ def param_shardings(mesh, params, *, fsdp: bool = True, overrides=()):
     specs = (fsdp_specs(params, mesh, overrides) if fsdp
              else param_specs(params, overrides))
     return named(mesh, specs, params)
-
-
-def index_shardings(mesh, tree, axis: str = "items"):
-    """Item-axis shardings for the retrieval service's index arrays.
-
-    Every leaf gets its LEADING dim partitioned on ``axis`` (posting tables
-    are stacked shard-major, factor/alive arrays are flat item-major — both
-    partition on their first dim).  Non-divisible dims fall back to
-    replication via the same sanitizer the model params use."""
-    def spec(x):
-        s = P(axis, *(None,) * (len(x.shape) - 1))
-        return NamedSharding(mesh, _sanitize(s, x.shape, mesh))
-
-    return jax.tree.map(spec, tree)
 
 
 def batch_specs(cfg: ModelConfig, mesh, batch) -> object:

@@ -161,8 +161,9 @@ def test_matches_pattern_oracle():
 
 def test_pack_patterns_roundtrip():
     tau, mask = _mapped(_factors(64, 16, 11))
-    bits = pack_patterns(tau, mask, CFG.p)
-    assert bits.shape == (64, -(-CFG.p // 32))
+    bits_t = pack_patterns(tau, mask, CFG.p)     # the kernel's layout
+    assert bits_t.shape == (-(-CFG.p // 32), 64)
+    bits = np.ascontiguousarray(bits_t.T)
     pop = np.unpackbits(bits.view(np.uint8), axis=1).sum(1)
     np.testing.assert_array_equal(pop, mask.sum(1))
     # set bits are exactly the masked destinations

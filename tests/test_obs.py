@@ -419,7 +419,9 @@ def test_tracing_leaves_the_answers_unchanged(quantize):
         a, b = on.query(u, exact=exact), off.query(u, exact=exact)
         np.testing.assert_array_equal(a.ids, b.ids)
         np.testing.assert_array_equal(a.scores, b.scores)
-    assert len(on.tracer.finished) == 4
+    roots = [sp.name for sp in on.tracer.finished]
+    # the empty service's build and the catalog's, then the four requests
+    assert roots[:2] == ["build", "build"] and len(roots) == 6
 
 
 # ----------------------------------------------------------- EventJournal

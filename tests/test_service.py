@@ -500,8 +500,13 @@ def test_index_mesh_places_shards_on_devices():
     items = _factors(128, 16, 21)
     idx = ShardedGamIndex.build(items, CFG, n_shards=2, min_overlap=1,
                                 mesh=mesh)
-    # stacked posting tables are partitioned over the item axis
-    assert not idx.tables.sharding.is_fully_replicated
+    # the posting segments stay in host memory as CSR (no query reads them
+    # on the device), while the kernel state is split over the items axis
+    assert len(idx.postings) == 2
+    assert all(isinstance(a, np.ndarray) for pair in idx.postings
+               for a in pair)
+    assert isinstance(idx.counts, np.ndarray)
+    assert not idx.metas[0].item_bits_t.sharding.is_fully_replicated
     # and the sharded query still matches the single-shard retriever
     users = _factors(4, 16, 22)
     svc = _sharded(items, n_shards=2, min_overlap=2, bucket=512, mesh=mesh)

@@ -111,16 +111,18 @@ def test_gam_score_compiles_for_v5e(one_chip):
 @pytest.mark.parametrize("k,quantized,kappa,n_pad", [
     (100, True, 40, 1_183_744),   # glove100-int8: int8 slab, pool of 40
     (64, False, 10, 292_864),     # lastfm64: f32 slab
+    (96, True, 40, 2_497_536),    # deep96-int8: one chip's quarter
 ])
 def test_benchmark_cell_shapes_compile_both_loops_for_v5e(one_chip, k,
                                                           quantized, kappa,
                                                           n_pad):
-    """The benchmark cells' launch, 32 queries over the whole catalog,
-    with the compacted and the full overlap loop in the one program: a
-    kernel each, both named as profile readers find them
+    """The benchmark cells' launch, 32 queries over the whole catalog (over
+    one chip's quarter of it in the four-chip cell), with the compacted and
+    the full overlap loop in the one program: a kernel each, both named as
+    profile readers find them
     (``%_gam_retrieve…``)."""
     words = -(-GamConfig(k=k, scheme="parse_tree", threshold=0.2).p // 32)
-    assert words == {100: 629, 64: 258}[k]
+    assert words == {100: 629, 64: 258, 96: 579}[k]
     compiled = _retrieve(one_chip, n_pad=n_pad, q=32, kappa=kappa, bn=BN,
                          quantized=quantized, min_overlap=2, k=k, words=words)
     calls = _kernel_calls(compiled)
