@@ -49,9 +49,17 @@ returns an arbitrary ``lax.top_k`` index that every consumer immediately
 filters on ``score <= NEG / 2`` — both paths are identical post-filter).
 
 Interpret mode (CPU) runs the same candidate/skip semantics but may use a
-``lax.top_k``-based merge (``loop_merge=False``); the Mosaic path uses a
-kappa-step selection loop since sort primitives do not lower to TPU.  Both
-merges realise the same total order and are cross-checked in tests.
+``lax.top_k``-based merge (``loop_merge=False``); the Mosaic path selects
+with argmax steps since sort primitives do not lower to TPU.  An entry
+enters a query's pool only if it beats the pool's kappa-th score, which
+few entries of a tile do once the pool has filled, so each tile counts
+them, takes the largest count over its query block (at most kappa), and
+runs that many steps over its beating entries, each inserted into the
+sorted accumulator: none on most tiles.  A tile where some query gains
+kappa entries runs kappa unrolled steps over the accumulator and the tile
+together.  The steps a launch ran come back per query block
+(``merge_steps``).  Both merges realise the same total order and are
+cross-checked in tests.
 """
 from __future__ import annotations
 
@@ -340,31 +348,82 @@ def _overlap_listed(qb, ib_ref, word_ref, *, fused_words):
 
 
 def _merge_topk(acc_s, acc_r, tile_s, tile_r, *, kappa, loop_merge):
-    """Running top-kappa merge under the total order (score desc, row asc).
+    """Running top-kappa merge under the total order (score desc, row asc)
+    -> (scores, rows, steps).
 
     Accumulator invariant (maintained by both merges): entries sorted by that
     order, rows pairwise distinct, NEG "empty" slots carry negative rows that
     beat the _NO_ROW sentinels of discarded items on score ties.
+
+    ``steps`` is the most tile entries any query row takes into its top
+    kappa, capped at kappa: the selection steps the Mosaic merge runs.
+    Only an entry above its row's kappa-th score can enter (an accumulator
+    entry holds a smaller row than any of this tile's, so one that only
+    ties the kappa-th loses), and of those at most kappa.
     """
-    cat_s = jnp.concatenate([acc_s, tile_s], axis=1)
-    cat_r = jnp.concatenate([acc_r, tile_r], axis=1)
+    beat = tile_s > jnp.min(acc_s, axis=1, keepdims=True)
+    steps = jnp.minimum(jnp.max(jnp.sum(beat.astype(jnp.int32), axis=1)),
+                        kappa)
+
+    def concat():
+        return (jnp.concatenate([acc_s, tile_s], axis=1),
+                jnp.concatenate([acc_r, tile_r], axis=1))
+
     if not loop_merge:
         # lax.top_k breaks score ties by position; accumulator entries precede
         # the tile and hold strictly smaller rows on ties (earlier blocks),
         # and tile columns are ascending-row — so position order == row order.
+        cat_s, cat_r = concat()
         new_s, idx = jax.lax.top_k(cat_s, kappa)
-        return new_s, jnp.take_along_axis(cat_r, idx, axis=1)
-    # Mosaic path: kappa-step argmax selection (sort ops don't lower to TPU).
-    # Rows are pairwise distinct, so removing by row erases exactly one entry.
-    sel_s, sel_r = [], []
-    for _ in range(kappa):
-        best = jnp.max(cat_s, axis=1, keepdims=True)
-        row = jnp.min(jnp.where(cat_s == best, cat_r, _NO_ROW + jnp.int32(1)),
-                      axis=1, keepdims=True)
-        sel_s.append(best)
-        sel_r.append(row)
-        cat_s = jnp.where(cat_r == row, -jnp.inf, cat_s)
-    return jnp.concatenate(sel_s, axis=1), jnp.concatenate(sel_r, axis=1)
+        return new_s, jnp.take_along_axis(cat_r, idx, axis=1), steps
+    # Mosaic path (sort ops don't lower to TPU): ``steps`` selection steps,
+    # zero on most tiles.  Rows are pairwise distinct, so removing an entry
+    # by its row erases exactly one.
+
+    def select_all():
+        # a tile where some row gains kappa entries: kappa argmax steps over
+        # the accumulator and the tile, unrolled (a v5e runs an unrolled
+        # step in about four fifths of the time of a step of the loop below)
+        cat_s, cat_r = concat()
+        sel_s, sel_r = [], []
+        for _ in range(kappa):
+            best = jnp.max(cat_s, axis=1, keepdims=True)
+            row = jnp.min(
+                jnp.where(cat_s == best, cat_r, _NO_ROW + jnp.int32(1)),
+                axis=1, keepdims=True)
+            sel_s.append(best)
+            sel_r.append(row)
+            cat_s = jnp.where(cat_r == row, -jnp.inf, cat_s)
+        return jnp.concatenate(sel_s, axis=1), jnp.concatenate(sel_r, axis=1)
+
+    def insert_beating():
+        # each step takes a row's best remaining beating entry (max score,
+        # then min row) and inserts it after the accumulator entries that
+        # beat or tie it, which hold smaller rows; the tail shifts one slot
+        # and the last entry drops.  A row that has run out draws -inf,
+        # which lands past the last slot.
+        slot = jax.lax.broadcasted_iota(jnp.int32, acc_s.shape, 1)
+
+        def step(_, carry):
+            cand_s, new_s, new_r = carry
+            best = jnp.max(cand_s, axis=1, keepdims=True)
+            row = jnp.min(jnp.where(cand_s == best, tile_r, _NO_ROW), axis=1,
+                          keepdims=True)
+            cand_s = jnp.where(tile_r == row, -jnp.inf, cand_s)
+            pos = jnp.sum((new_s >= best).astype(jnp.int32), axis=1,
+                          keepdims=True)
+            new_s = jnp.where(slot < pos, new_s, jnp.where(
+                slot == pos, best, pltpu.roll(new_s, 1, 1)))
+            new_r = jnp.where(slot < pos, new_r, jnp.where(
+                slot == pos, row, pltpu.roll(new_r, 1, 1)))
+            return cand_s, new_s, new_r
+
+        _, new_s, new_r = jax.lax.fori_loop(
+            0, steps, step, (jnp.where(beat, tile_s, -jnp.inf), acc_s, acc_r))
+        return new_s, new_r
+
+    new_s, new_r = jax.lax.cond(steps == kappa, select_all, insert_beating)
+    return new_s, new_r, steps
 
 
 def _kernel(skip_ref, *refs, kappa, min_overlap, bn, n_blocks, words,
@@ -375,10 +434,11 @@ def _kernel(skip_ref, *refs, kappa, min_overlap, bn, n_blocks, words,
     u_ref, qb_ref, v_ref, *rest = refs
     if quantized:
         # the int8 factor tile's per-block scales (SMEM) precede the bit refs
-        sc_ref, ib_ref, sp_ref, al_ref, vals_ref, rows_ref, cnt_ref = rest
+        (sc_ref, ib_ref, sp_ref, al_ref, vals_ref, rows_ref, cnt_ref,
+         steps_ref) = rest
     else:
         sc_ref = None
-        ib_ref, sp_ref, al_ref, vals_ref, rows_ref, cnt_ref = rest
+        ib_ref, sp_ref, al_ref, vals_ref, rows_ref, cnt_ref, steps_ref = rest
     j = pl.program_id(1)
     bq = u_ref.shape[0]
 
@@ -387,6 +447,7 @@ def _kernel(skip_ref, *refs, kappa, min_overlap, bn, n_blocks, words,
         vals_ref[...] = jnp.full((bq, kappa), NEG, jnp.float32)
         # distinct negative sentinel rows: deterministic NEG-tie resolution
         rows_ref[...] = -1 - jax.lax.broadcasted_iota(jnp.int32, (bq, kappa), 1)
+        steps_ref[...] = jnp.zeros((bq, 1), jnp.int32)
 
     cnt_ref[...] = jnp.zeros((bq, 1), jnp.int32)
 
@@ -413,10 +474,12 @@ def _kernel(skip_ref, *refs, kappa, min_overlap, bn, n_blocks, words,
         col = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
         tile_s = jnp.where(cand, scores, NEG)
         tile_r = jnp.where(cand, j * bn + col, _NO_ROW + col)
-        new_s, new_r = _merge_topk(vals_ref[...], rows_ref[...], tile_s,
-                                   tile_r, kappa=kappa, loop_merge=loop_merge)
+        new_s, new_r, steps = _merge_topk(
+            vals_ref[...], rows_ref[...], tile_s, tile_r, kappa=kappa,
+            loop_merge=loop_merge)
         vals_ref[...] = new_s
         rows_ref[...] = new_r
+        steps_ref[...] += steps
 
 
 class GamRetrieveResult(NamedTuple):
@@ -426,6 +489,8 @@ class GamRetrieveResult(NamedTuple):
     skipped: jax.Array     # (q_blocks, n_blocks) bool — tiles never scored
     loop_words: jax.Array  # (q_blocks,) int32 pattern words the overlap
                            # loop ran over (the listed words, or all)
+    merge_steps: jax.Array  # (q_blocks,) int32 selection steps the merge
+                            # ran over the item blocks (kappa a tile at most)
 
 
 def _launch(q_bits, word_idx, union, ibT, up, fp, extra, extra_specs,
@@ -434,8 +499,8 @@ def _launch(q_bits, word_idx, union, ibT, up, fp, extra, extra_specs,
     """The block prepass and the kernel over ``q_bits``' pattern words:
     every word of ``ibT`` (words, n_pad) when ``word_idx`` is None, else
     ``q_bits`` (qp, w) and ``union`` (nb, w) hold the words ``word_idx``
-    (w,) names.  -> padded (vals, rows, counts) and the (qp / bq, nb) skip
-    map."""
+    (w,) names.  -> padded (vals, rows, counts), the (qp / bq, nb) skip
+    map and the (qp, 1) merge steps (each query block's in all its rows)."""
     qp, k = up.shape
     nb, words = union.shape
 
@@ -473,21 +538,23 @@ def _launch(q_bits, word_idx, union, ibT, up, fp, extra, extra_specs,
             pl.BlockSpec((bq, kappa), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, kappa), lambda i, j: (i, 0)),
             pl.BlockSpec((pl.squeezed, bq, 1), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((qp, kappa), jnp.float32),
             jax.ShapeDtypeStruct((qp, kappa), jnp.int32),
             jax.ShapeDtypeStruct((nb, qp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         ),
         interpret=interpret,
     )
     # inside a ``lax.cond`` branch too, the kernel's custom call keeps the
     # program's name (``%_gam_retrieve.<n>``), which profile readers match
     with jax.named_scope("_gam_retrieve"):
-        vals, rows, cnt = kernel(skip.reshape(-1),
-                                 *([word_idx] if compact else []), up,
-                                 q_bits, fp, *extra, ibT, spill8, al8)
-    return vals, rows, cnt, skip
+        vals, rows, cnt, steps = kernel(skip.reshape(-1),
+                                        *([word_idx] if compact else []), up,
+                                        q_bits, fp, *extra, ibT, spill8, al8)
+    return vals, rows, cnt, skip, steps
 
 
 @partial(jax.jit, static_argnames=("kappa", "min_overlap", "bq", "bn",
@@ -560,17 +627,18 @@ def _gam_retrieve(users, factors, scales, q_tau, q_mask, alive, ibT, union,
                       idx, union[:, idx])
 
     if words <= COMPACT_WORDS:
-        vals, rows, cnt, skip = full()
+        vals, rows, cnt, skip, steps = full()
         width = jnp.int32(words)
     else:
         fits = n_active <= COMPACT_WORDS
-        vals, rows, cnt, skip = jax.lax.cond(fits, compacted, full)
+        vals, rows, cnt, skip, steps = jax.lax.cond(fits, compacted, full)
         width = jnp.where(fits, COMPACT_WORDS, words)
 
     vals = vals[:q]
     rows = jnp.where(vals <= NEG / 2, -1, rows[:q])
     return GamRetrieveResult(vals, rows, cnt[:, :q, 0].T, skip == 1,
-                             jnp.full((qp // bq,), width, jnp.int32))
+                             jnp.full((qp // bq,), width, jnp.int32),
+                             steps[::bq, 0])
 
 
 def rerank_pool(pool_res: GamRetrieveResult, users, factors,
@@ -606,7 +674,7 @@ def rerank_pool(pool_res: GamRetrieveResult, users, factors,
                              rows_p[qi][order])
     return GamRetrieveResult(jnp.asarray(out_s), jnp.asarray(out_r),
                              pool_res.blk_counts, pool_res.skipped,
-                             pool_res.loop_words)
+                             pool_res.loop_words, pool_res.merge_steps)
 
 
 def gam_retrieve(users: jax.Array, factors: jax.Array, q_tau: jax.Array,

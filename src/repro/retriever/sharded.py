@@ -672,7 +672,8 @@ class ShardedRetriever(Retriever):
         stats = {"shard_candidates": np.asarray(res.shard_candidates),
                  "block_candidates": res.block_candidates,
                  "tiles_skipped_frac": res.tiles_skipped_frac,
-                 "active_words_frac": res.active_words_frac}
+                 "active_words_frac": res.active_words_frac,
+                 "merge_steps_frac": res.merge_steps_frac}
         if explain:
             stats["tile_skips"] = res.tile_skips
         return scores, ids, stats
@@ -747,7 +748,8 @@ class ShardedRetriever(Retriever):
             posting_load=self.base.posting_load().tolist(),
             metrics=self.metrics.snapshot(),
         )
-        for key in ("tiles_skipped_frac", "active_words_frac"):
+        for key in ("tiles_skipped_frac", "active_words_frac",
+                    "merge_steps_frac"):
             if key in self._last_query_stats:
                 out[key] = self._last_query_stats[key]
         if self.cache is not None:

@@ -89,6 +89,8 @@ class ShardTopK:
     tiles_skipped_frac: float = 0.0  # fraction of (Q_blk, N_blk) tiles pruned
     active_words_frac: float = 1.0   # pattern words the overlap loop ran
                                      # over, as a fraction of all words
+    merge_steps_frac: float = 1.0    # selection steps the merge ran, as a
+                                     # fraction of kappa a scored tile
     tile_skips: np.ndarray | None = None  # (Q, n_blocks) bool prepass skips
                                           # (explain-only; None by default)
 
@@ -776,7 +778,8 @@ class ShardedGamIndex:
         (``min_overlap=0``) — the brute-force reference path.
 
         ``tracer`` wraps each launch's enqueue (``gam_retrieve``, which
-        takes the launch's ``active_words_frac`` once it is read), the wait
+        takes the launch's ``active_words_frac`` and ``merge_steps_frac``
+        once it is read), the wait
         for it and its int8 re-rank (:func:`collect_launch`) and the host
         merge in spans; ``collect_tile_skips`` additionally expands the
         kernel's per-query-block skip map to a per-query (Q, n_blocks) bool in
@@ -806,21 +809,29 @@ class ShardedGamIndex:
                             alive=alive, rerank_factor=self.rerank_factor,
                             rerank=False)
                 launched.append((res, meta.quantize == "int8", u, fac))
-                spans.append((sp, meta.words))
+                # the launch's pool width: an int8 pool is re-ranked to kappa
+                spans.append((sp, meta.words, res.vals.shape[1]))
                 offsets.append(self.partition.group_rows(g)[0] + off)
         # int8 pools are re-ranked on the host only once every launch is
         # queued: the re-rank waits on its launch, and would otherwise keep
         # a mesh's devices from running together
         results = [collect_launch(res, u, fac, kappa, int8, tracer)
                    for res, int8, u, fac in launched]
-        # the compacted overlap loop's width, per launch and over the batch
-        looped, total = 0, 0
-        for r, (sp, words) in zip(results, spans):
+        # the compacted overlap loop's width and the merge's steps, per
+        # launch and over the batch
+        looped, total, stepped, full = 0, 0, 0, 0
+        for r, (sp, words, pool) in zip(results, spans):
             lw = np.asarray(r.loop_words)
-            sp.set(active_words_frac=float(lw.mean()) / words)
+            steps = int(np.asarray(r.merge_steps).sum())
+            scored = pool * int(np.logical_not(np.asarray(r.skipped)).sum())
+            sp.set(active_words_frac=float(lw.mean()) / words,
+                   merge_steps_frac=steps / max(scored, 1))
             looped += int(lw.sum())
             total += lw.size * words
+            stepped += steps
+            full += scored
         words_frac = looped / max(total, 1)
+        steps_frac = stepped / max(full, 1)
         skips = (np.concatenate([expand_tile_skips(r.skipped, q)
                                  for r in results], axis=1)
                  if collect_tile_skips and results else None)
@@ -833,6 +844,7 @@ class ShardedGamIndex:
                              block_candidates=blk,
                              tiles_skipped_frac=float(res.skipped.mean()),
                              active_words_frac=words_frac,
+                             merge_steps_frac=steps_frac,
                              tile_skips=skips)
         with tracer.span("group_merge", n_launches=len(results)):
             exported = [export_topk(r.vals, r.rows, offset=off)
@@ -852,6 +864,7 @@ class ShardedGamIndex:
                          block_candidates=blk,
                          tiles_skipped_frac=skipped / max(tiles, 1),
                          active_words_frac=words_frac,
+                         merge_steps_frac=steps_frac,
                          tile_skips=skips)
 
     def query_dense_reference(self, users: jax.Array, q_tau: jax.Array,

@@ -407,3 +407,169 @@ def test_sharded_query_reports_active_words_frac():
     assert res.active_words_frac == 128 / WORDS48
     svc.query(users, 10)
     assert 0.0 < svc.stats()["active_words_frac"] <= 1.0
+
+
+# ------------------------------------------------- the bounded Mosaic merge
+
+
+def _scaled(scale, seed):
+    """Rows along one direction, row i scaled by ``scale[i]``, and eight
+    queries near that direction: every query orders the rows by scale."""
+    v = _factors(1, 16, seed)[0]
+    rng = np.random.default_rng(seed)
+    users = (v + 0.05 * rng.normal(size=(8, 16))).astype(np.float32)
+    return (v[None, :] * np.asarray(scale, np.float32)[:, None]), users
+
+
+def _merge_case(case):
+    """-> items, users, retrieve keywords, alive, bucket, expected merge
+    steps a query block (None: the numpy count alone decides)."""
+    n, kappa, bn = 256, 10, 32
+    tiles = n // bn
+    kw = dict(kappa=kappa, mo=1, bn=bn, quantize="none")
+    ramp = np.linspace(0.5, 2.0, n)
+    if case == "ascending":          # every tile beats the pool
+        items, users = _scaled(ramp, 41)
+        return items, users, kw, None, 512, kappa * tiles
+    if case == "descending":         # only the first tile enters the pool
+        items, users = _scaled(ramp[::-1], 42)
+        return items, users, kw, None, 512, kappa
+    if case == "all-equal":          # ties break by row: the first tile's
+        items, users = _scaled(np.ones(n), 43)
+        return items, users, kw, None, 512, kappa
+    if case == "tile-beats-kappa":   # a middle tile holds 32 new entries
+        scale = ramp[::-1].copy()
+        scale[3 * bn:4 * bn] = 3.0
+        items, users = _scaled(scale, 44)
+        return items, users, kw, None, 512, 2 * kappa
+    items = _factors(300, 16, 45)
+    users = _factors(11, 16, 46)
+    kw.update(bn=64)
+    if case == "spill-and-dead":
+        alive = np.ones(300, bool)
+        alive[::4] = False
+        return items, users, dict(kw, mo=2), alive, 4, None
+    if case == "int8-pool":
+        return items, users, dict(kw, mo=2, quantize="int8"), None, 512, None
+    assert case == "exact"
+    return items, users, dict(kw, mo=0), None, 512, None
+
+
+@pytest.mark.parametrize("case", ["ascending", "descending", "all-equal",
+                                  "tile-beats-kappa", "spill-and-dead",
+                                  "int8-pool", "exact"])
+def test_bounded_merge_matches_top_k_merge_and_oracle(case):
+    """The Mosaic merge, which runs only as many selection steps as the
+    tile's entries that beat a query's kappa-th score (at most kappa), is
+    the ``lax.top_k`` merge bit for bit (the int8 pool too) and the dense
+    ``masked_topk`` oracle's answer, on catalogs where every tile, only
+    the first, or one middle tile past kappa entries enters the pool."""
+    items, users, kw, alive, bucket, want_steps = _merge_case(case)
+    tau, mask = _mapped(items)
+    q_tau, q_mask = _mapped(users)
+    kappa = kw["kappa"]
+    dev = DeviceIndex.build(tau, CFG.p, bucket, mask=mask)
+    meta = build_retrieval_meta(
+        tau, mask, CFG.p, spill_rows=np.asarray(dev.spill), bn=kw["bn"],
+        factors=items if kw["quantize"] == "int8" else None,
+        quantize=kw["quantize"])
+
+    def run(loop_merge, rerank=True):
+        return gam_retrieve(users, items, q_tau, q_mask, meta, kappa,
+                            min_overlap=kw["mo"], alive=alive, bq=8,
+                            interpret=True, loop_merge=loop_merge,
+                            rerank=rerank)
+
+    bounded, top_k = run(True), run(False)
+    for field in bounded._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(bounded, field)),
+                                      np.asarray(getattr(top_k, field)),
+                                      err_msg=field)
+    if kw["quantize"] == "int8":
+        pool_b, pool_t = run(True, rerank=False), run(False, rerank=False)
+        assert np.asarray(pool_b.vals).shape[1] == 4 * kappa
+        for field in pool_b._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(pool_b, field)),
+                                          np.asarray(getattr(pool_t, field)),
+                                          err_msg=field)
+    masks = dev.batch_candidate_mask(jnp.asarray(q_tau), kw["mo"],
+                                     jnp.asarray(q_mask))
+    if alive is not None:
+        masks = masks & jnp.asarray(alive)[None, :]
+    vals, ids = masked_topk(jnp.asarray(users), jnp.asarray(items), masks,
+                            kappa)
+    vals, ids = np.asarray(vals), np.asarray(ids)
+    _assert_same_topk(bounded, np.where(vals <= NEG / 2, -1, ids), vals)
+    if want_steps is not None:
+        np.testing.assert_array_equal(np.asarray(bounded.merge_steps),
+                                      want_steps)
+
+
+def _numpy_merge_steps(scores, cand, kappa, bq, bn):
+    """Per query block: over the item blocks, the most entries any of its
+    queries has above its running kappa-th score (capped at kappa), the
+    running top kappa kept under (score desc, row asc)."""
+    q, n = scores.shape
+    out = np.zeros(-(-q // bq), np.int64)
+    pool = [[] for _ in range(q)]               # (score, row), best first
+    for lo in range(0, n, bn):
+        beat = np.zeros(q, np.int64)
+        for i in range(q):
+            rows = [r for r in range(lo, min(lo + bn, n)) if cand[i, r]]
+            thr = pool[i][kappa - 1][0] if len(pool[i]) >= kappa else -np.inf
+            beat[i] = sum(scores[i, r] > thr for r in rows)
+            pool[i] = sorted(pool[i] + [(scores[i, r], r) for r in rows],
+                             key=lambda e: (-e[0], e[1]))[:kappa]
+        for b in range(len(out)):
+            out[b] += min(kappa, beat[b * bq:(b + 1) * bq].max())
+    return out
+
+
+@pytest.mark.parametrize("loop_merge", [False, True])
+def test_merge_steps_counts_entries_beating_the_kappa_th(loop_merge):
+    """``merge_steps`` is a numpy count of the tile entries above each
+    query's running kappa-th score, capped at kappa and maxed over the
+    query block, summed over item blocks.  Small integer factors make
+    every score exact, ties included."""
+    rng = np.random.default_rng(47)
+    items = rng.integers(-3, 4, size=(300, 16)).astype(np.float32)
+    users = rng.integers(-3, 4, size=(16, 16)).astype(np.float32)
+    tau, mask = _mapped(items)
+    q_tau, q_mask = _mapped(users)
+    dev = DeviceIndex.build(tau, CFG.p, 512, mask=mask)
+    meta = build_retrieval_meta(tau, mask, CFG.p,
+                                spill_rows=np.asarray(dev.spill), bn=32)
+    res = gam_retrieve(users, items, q_tau, q_mask, meta, 6, min_overlap=2,
+                       bq=8, interpret=True, loop_merge=loop_merge)
+    cand = np.asarray(dev.batch_candidate_mask(jnp.asarray(q_tau), 2,
+                                               jnp.asarray(q_mask)))
+    want = _numpy_merge_steps(users @ items.T, cand, 6, 8, 32)
+    assert 0 < want.sum() < 6 * 10 * 2            # not every tile runs all
+    np.testing.assert_array_equal(np.asarray(res.merge_steps), want)
+    vals, ids = masked_topk(jnp.asarray(users), jnp.asarray(items),
+                            jnp.asarray(cand), 6)
+    vals, ids = np.asarray(vals), np.asarray(ids)
+    _assert_same_topk(res, np.where(vals <= NEG / 2, -1, ids), vals)
+
+
+def test_sharded_query_reports_merge_steps_frac():
+    """``ShardTopK.merge_steps_frac`` is the launch's merge steps over
+    kappa a scored tile, and the ``sharded`` retriever keeps the last
+    query's in ``stats()``."""
+    items = _factors(600, 16, 48)
+    users = _factors(12, 16, 49)
+    svc = open_retriever(
+        RetrieverSpec(cfg=CFG, backend="sharded", n_shards=2, min_overlap=2,
+                      kappa=5, bucket=512), items=items)
+    tau, vals_ = sparse_map(jnp.asarray(users), CFG)
+    q_mask = jnp.asarray(np.asarray(vals_) != 0.0)
+    res = svc.base.query(jnp.asarray(users), tau, q_mask, 5)
+    (_, meta, fac, alive), = svc.base._launch_units(0)
+    direct = gam_retrieve(jnp.asarray(users), fac, tau, q_mask, meta, 5,
+                          min_overlap=2, alive=alive, interpret=True)
+    scored = int(np.logical_not(np.asarray(direct.skipped)).sum())
+    steps = int(np.asarray(direct.merge_steps).sum())
+    assert 0 < steps < 5 * scored              # the pool fills, then few
+    assert res.merge_steps_frac == steps / (5 * scored)
+    svc.query(users, 5)
+    assert 0.0 < svc.stats()["merge_steps_frac"] < 1.0

@@ -13,6 +13,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax._src import core as jax_core
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.mapping import GamConfig
@@ -51,8 +52,8 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _retrieve(sharding, *, n_pad, q, kappa, bn, quantized, min_overlap,
-              k=K, words=WORDS):
+def _trace(sharding, *, n_pad, q, kappa, bn, quantized, min_overlap,
+           k=K, words=WORDS):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -63,9 +64,47 @@ def _retrieve(sharding, *, n_pad, q, kappa, bn, quantized, min_overlap,
             s((q, k), jnp.int32), s((q, k), jnp.bool_), s((n_pad,), jnp.bool_),
             s((words, n_pad), jnp.uint32), s((nb, words), jnp.uint32),
             s((nb,), jnp.bool_), s((1, n_pad), jnp.int8))
-    return _gam_retrieve.lower(
+    return _gam_retrieve.trace(
         *args, kappa=kappa, min_overlap=min_overlap, bq=32, bn=bn,
-        words=words, n_pad=n_pad, interpret=False, loop_merge=True).compile()
+        words=words, n_pad=n_pad, interpret=False, loop_merge=True)
+
+
+def _retrieve(sharding, **kw):
+    return _trace(sharding, **kw).lower().compile()
+
+
+def _primitives(jaxpr) -> set[str]:
+    """Names of the primitives in ``jaxpr`` and in the jaxprs it holds."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax_core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+def _merge_branches(jaxpr) -> list[list[list[set[str]]]]:
+    """For each Pallas kernel in ``jaxpr`` (nested ones too), each
+    ``cond`` of its body (a ``pl.when`` is one too) as the primitives of
+    each of its branches."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append([[_primitives(b.jaxpr) for b in c.params["branches"]]
+                        for c in _eqns(eqn.params["jaxpr"])
+                        if c.primitive.name == "cond"])
+        else:
+            for sub in jax_core.jaxprs_in_params(eqn.params):
+                out += _merge_branches(sub)
+    return out
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, nested ones too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax_core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
 
 
 def _kernel_calls(compiled) -> list[str]:
@@ -123,8 +162,24 @@ def test_benchmark_cell_shapes_compile_both_loops_for_v5e(one_chip, k,
     (``%_gam_retrieve…``)."""
     words = -(-GamConfig(k=k, scheme="parse_tree", threshold=0.2).p // 32)
     assert words == {100: 629, 64: 258, 96: 579}[k]
-    compiled = _retrieve(one_chip, n_pad=n_pad, q=32, kappa=kappa, bn=BN,
-                         quantized=quantized, min_overlap=2, k=k, words=words)
-    calls = _kernel_calls(compiled)
+    traced = _trace(one_chip, n_pad=n_pad, q=32, kappa=kappa, bn=BN,
+                    quantized=quantized, min_overlap=2, k=k, words=words)
+    calls = _kernel_calls(traced.lower().compile())
     assert len(calls) == 2, calls
     assert all(c.startswith("%_gam_retrieve") for c in calls), calls
+    # each kernel holds both of the merge's branches behind one cond: the
+    # loop of as many steps as the tile's beating entries (a while loop
+    # that rolls the accumulator) and kappa unrolled steps over the
+    # accumulator and the tile together
+    kernels = _merge_branches(traced.jaxpr.jaxpr)
+    assert len(kernels) == 2, kernels
+    loop = {"while", "roll"}
+    for conds in kernels:
+        merges = [c for c in conds if len(c) == 2
+                  and not any("cond" in b for b in c)
+                  and any(loop <= b for b in c)]
+        assert len(merges) == 1, conds
+        looped, unrolled = sorted(merges[0], key=lambda b: "while" in b,
+                                  reverse=True)
+        assert loop <= looped and not unrolled & loop, merges
+        assert "concatenate" in unrolled, unrolled
