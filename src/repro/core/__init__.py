@@ -2,15 +2,11 @@
 
 Canonical exports: ``pattern_overlap`` (and the rest of the mapping/
 tessellation toolkit) live HERE; the retrieval lifecycle moved to
-``repro.retriever`` (one spec, pluggable backends, snapshot/restore) —
-``RetrievalResult`` is re-exported from there for the legacy spelling, and
-``BruteForceRetriever``/``GamRetriever`` are deprecation shims over the
-``brute``/``gam``/``gam-device`` backends.
+``repro.retriever`` (one spec, pluggable backends, snapshot/restore);
+``RetrievalResult`` is re-exported from there.
 """
 from repro.core.mapping import GamConfig, densify, pattern_overlap, sparse_map
 from repro.core.retrieval import (
-    BruteForceRetriever,
-    GamRetriever,
     RetrievalResult,
     masked_topk,
     recovery_accuracy,
@@ -28,8 +24,6 @@ __all__ = [
     "densify",
     "pattern_overlap",
     "sparse_map",
-    "BruteForceRetriever",
-    "GamRetriever",
     "RetrievalResult",
     "masked_topk",
     "recovery_accuracy",

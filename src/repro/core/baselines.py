@@ -8,9 +8,10 @@
                  hash = indices of the top-l projections (an l-ary code).
 * PCA-tree     — median splits along principal eigenvectors [Verma et al. '09].
 
-All expose ``query(users, kappa) -> RetrievalResult`` like GamRetriever, with
-candidate extraction by exact hash/leaf match (tree-based lookup, per §5.1 —
-Hamming-ranking against every item would defeat the purpose).
+All expose ``query(users, kappa) -> RetrievalResult`` like the ``gam``
+backend, with candidate extraction by exact hash/leaf match (tree-based
+lookup, per §5.1 — Hamming-ranking against every item would defeat the
+purpose).
 """
 from __future__ import annotations
 

@@ -1,19 +1,12 @@
-"""Legacy retrieval entry points + shared retrieval metrics.
+"""Shared retrieval oracle and metric.
 
-The retriever implementations moved behind the unified API in
-``repro.retriever`` (one spec, one lifecycle, pluggable backends, snapshot/
-restore).  ``GamRetriever`` and ``BruteForceRetriever`` remain here as thin
-deprecation shims for one release — they emit ``DeprecationWarning`` naming
-the new spelling and delegate everything to the equivalent backend.
-
-Still canonical here: :func:`masked_topk` (the dense bit-exact oracle the
-fused kernel is tested against) and :func:`recovery_accuracy` (the paper's
-§6 metric).  :class:`RetrievalResult` is re-exported from its new home,
-``repro.retriever``.
+The retrievers live behind the unified API in ``repro.retriever`` (one
+spec, one lifecycle, pluggable backends, snapshot/restore).  Canonical
+here: :func:`masked_topk` (the dense bit-exact oracle the fused kernel is
+tested against) and :func:`recovery_accuracy` (the paper's §6 metric).
+:class:`RetrievalResult` is re-exported from its home, ``repro.retriever``.
 """
 from __future__ import annotations
-
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +15,7 @@ import numpy as np
 from repro.kernels.ops import gam_score
 from repro.retriever.types import RetrievalResult
 
-__all__ = ["BruteForceRetriever", "GamRetriever", "RetrievalResult",
-           "masked_topk", "recovery_accuracy"]
+__all__ = ["RetrievalResult", "masked_topk", "recovery_accuracy"]
 
 
 def masked_topk(users: jax.Array, items: jax.Array, masks: jax.Array,
@@ -36,67 +28,11 @@ def masked_topk(users: jax.Array, items: jax.Array, masks: jax.Array,
     Serving no longer goes through here — the fused ``gam_retrieve`` kernel
     realises the identical (score desc, row asc) order without ever
     materialising the (Q, N) mask/score tensors — but this stays as the
-    bit-exact oracle the fused path is tested and benchmarked against.
+    bit-exact oracle the fused path is tested against.
     """
     scores = gam_score(users, items, masks)
     vals, ids = jax.lax.top_k(scores, kappa)
     return vals, ids.astype(jnp.int32)
-
-
-def _deprecated(old: str, new: str) -> None:
-    warnings.warn(f"{old} is deprecated; use {new} "
-                  "(see repro.retriever — removed after one release)",
-                  DeprecationWarning, stacklevel=3)
-
-
-class BruteForceRetriever:
-    """DEPRECATED shim — use ``open_retriever(RetrieverSpec(cfg=...,
-    backend='brute'))``.  Exact top-kappa by scoring every item."""
-
-    def __init__(self, items: np.ndarray):
-        _deprecated("core.retrieval.BruteForceRetriever(items)",
-                    "repro.retriever.open_retriever(RetrieverSpec("
-                    "cfg=GamConfig(k=...), backend='brute'), items=items)")
-        from repro.retriever import RetrieverSpec, open_retriever
-        items = np.asarray(items, np.float32)
-        spec = RetrieverSpec(
-            cfg=_plain_cfg(items.shape[1]), backend="brute")
-        self._impl = open_retriever(spec, items=items)
-
-    def __getattr__(self, name):
-        if name == "_impl":      # not set yet (e.g. unpickling a bare shell)
-            raise AttributeError(name)
-        return getattr(self._impl, name)
-
-
-class GamRetriever:
-    """DEPRECATED shim — use ``open_retriever(RetrieverSpec(cfg=cfg,
-    backend='gam'|'gam-device', ...))``.  Paper's method: phi-map items
-    once, inverted index, candidate-only scoring."""
-
-    def __init__(self, items: np.ndarray, cfg, min_overlap: int = 1,
-                 device: bool = False, bucket: int = 256,
-                 whiten: bool = False):
-        backend = "gam-device" if device else "gam"
-        _deprecated("core.retrieval.GamRetriever(items, cfg, ...)",
-                    f"repro.retriever.open_retriever(RetrieverSpec(cfg=cfg, "
-                    f"backend={backend!r}, min_overlap=..., bucket=..., "
-                    f"whiten=...), items=items)")
-        from repro.retriever import RetrieverSpec, open_retriever
-        spec = RetrieverSpec(cfg=cfg, backend=backend,
-                             min_overlap=min_overlap, bucket=bucket,
-                             whiten=whiten)
-        self._impl = open_retriever(spec, items=items)
-
-    def __getattr__(self, name):
-        if name == "_impl":      # not set yet (e.g. unpickling a bare shell)
-            raise AttributeError(name)
-        return getattr(self._impl, name)
-
-
-def _plain_cfg(k: int):
-    from repro.core.mapping import GamConfig
-    return GamConfig(k=k)
 
 
 def recovery_accuracy(retrieved_ids: np.ndarray, true_ids: np.ndarray) -> np.ndarray:

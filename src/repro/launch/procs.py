@@ -1,8 +1,8 @@
 """Local multi-process ``jax.distributed`` spawn recipe (demo/CI).
 
-Three surfaces spawn cooperating worker processes on one machine — the
-``launch/serve.py --hosts N`` driver, the ``benchmarks/service_bench.py``
-multi-host scenario and ``tests/multihost/run_multiprocess.py`` — and they
+Two surfaces spawn cooperating worker processes on one machine — the
+``launch/serve.py --hosts N`` driver and
+``tests/multihost/run_multiprocess.py`` — and they
 must agree on the fiddly parts: a free coordinator port, a worker
 environment pinned to the CPU backend with the forced-host-device-count
 flag scrubbed (each worker owns exactly one local device), and supervision

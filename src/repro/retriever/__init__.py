@@ -47,10 +47,7 @@ The registry is string-keyed and lazily resolved (same importlib pattern as
 :func:`register_backend` without touching any caller.
 
 This module is the canonical home of :class:`RetrievalResult` and
-:class:`UnsupportedOp`; ``repro.core`` re-exports the former for the legacy
-spelling.  Legacy constructors (``core.retrieval.GamRetriever``,
-``core.retrieval.BruteForceRetriever``, ``service.GamService``) remain as
-deprecation shims over these backends for one release.
+:class:`UnsupportedOp`; ``repro.core`` re-exports the former.
 """
 from repro.retriever.api import (
     BACKEND_IDS,

@@ -30,7 +30,7 @@ from repro.compress.postings import CompressedPostings, decode_postings, \
 from repro.core.inverted_index import (CompressedInvertedIndex, DeviceIndex,
                                        InvertedIndex, csr_to_table,
                                        table_to_csr)
-from repro.core.mapping import GamConfig, sparse_map
+from repro.core.mapping import sparse_map
 from repro.kernels.gam_retrieve import (RetrievalMeta, build_retrieval_meta,
                                         expand_tile_skips, quantize_meta)
 from repro.kernels.gam_score import NEG
@@ -62,16 +62,6 @@ class GamIndexRetriever(Retriever):
         self.device_index: DeviceIndex | None = None
         self._items_dev: jax.Array | None = None
         self._retrieve_meta: RetrievalMeta | None = None
-
-    # convenience aliases so code written against the old GamRetriever
-    # attribute surface keeps reading naturally
-    @property
-    def cfg(self) -> GamConfig:
-        return self.spec.cfg
-
-    @property
-    def min_overlap(self) -> int:
-        return self.spec.min_overlap
 
     # ------------------------------------------------------------ lifecycle
 

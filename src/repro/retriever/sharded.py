@@ -1,7 +1,6 @@
 """``sharded`` backend: the streaming service tier behind the unified API.
 
-Owns the three storage tiers and the request plumbing that used to live in
-``service.GamService`` (now a deprecation shim over this class):
+Owns the three storage tiers and the request plumbing:
 
   * ``ShardedGamIndex`` — the compacted main segment, item-axis sharded
     according to a (possibly skew-aware) ``Partition``;

@@ -11,9 +11,6 @@ ties them together is the unified-API ``sharded`` backend
                                      n_shards=4, min_overlap=2),
                        items=factors, ids=item_ids)
 
-``GamService`` remains as a deprecation shim over that backend for one
-release.
-
 Architecture
 ============
 
@@ -58,8 +55,7 @@ Components
 
 ``ServiceMetrics`` (``metrics.py``)
     QPS, latency percentiles, batch occupancy, discard fraction and
-    shard-balance counters; surfaced by ``launch/serve.py --service`` and
-    ``benchmarks/service_bench.py`` (throughput-vs-latency curve).
+    shard-balance counters; surfaced by ``launch/serve.py --service``.
 
 ``CompactionPlanner`` (``compaction.py``)
     Background compaction as a resumable state machine: the replacement
@@ -113,7 +109,6 @@ from repro.service.microbatch import Microbatcher, QueryResult
 from repro.service.qos import (DEGRADE_RUNGS, HealthTracker, QosPolicy,
                                RequestShed, ResultEvicted)
 from repro.service.repartition import MapCache, Partition, Repartitioner
-from repro.service.service import GamService, ServiceConfig
 from repro.service.sharded_index import ShardedGamIndex, ShardTopK
 
 __all__ = [
@@ -124,7 +119,6 @@ __all__ = [
     "FaultInjected",
     "FaultInjector",
     "FaultSpec",
-    "GamService",
     "HealthTracker",
     "HostPlacement",
     "LoadGenerator",
@@ -139,7 +133,6 @@ __all__ = [
     "Repartitioner",
     "ResultCache",
     "ResultEvicted",
-    "ServiceConfig",
     "ServiceMetrics",
     "ShardTopK",
     "ShardedGamIndex",

@@ -19,8 +19,8 @@ bursts.  This module generates that traffic deterministically:
 
 Everything is a pure function of ``(profile, seed)``: two generators with
 the same profile emit identical queries, upserts and arrival times, which
-is what lets ``benchmarks/service_bench.py`` replay one stream against a
-cache-on and a cache-off service and diff the answers bit-for-bit.  See
+is what lets one stream be replayed against a cache-on and a cache-off
+service and the answers diffed bit-for-bit.  See
 ``docs/load_testing.md`` for the model and parameter guidance.
 """
 from __future__ import annotations

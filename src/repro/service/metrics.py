@@ -3,11 +3,11 @@ shard/block balance and maintenance (compaction / repartition) counters.
 
 Pure-Python accumulation (no jax) so it can be updated from the request path
 without touching device state; ``snapshot()`` renders the dict that
-``launch/serve.py --service`` prints and ``benchmarks/service_bench.py``
-records.  The per-shard and per-block candidate accumulators double as the
-load signal the :class:`~repro.service.repartition.Repartitioner` reads:
-``shard_skew()`` / ``block_skew()`` (max/mean) decide when a rebalancing
-compaction is worth scheduling.
+``launch/serve.py --service`` prints.  The per-shard and per-block
+candidate accumulators double as the load signal the
+:class:`~repro.service.repartition.Repartitioner` reads: ``shard_skew()`` /
+``block_skew()`` (max/mean) decide when a rebalancing compaction is worth
+scheduling.
 
 Latency / queue-wait / service-time / occupancy / discard distributions live
 in fixed log-spaced-bucket :class:`~repro.obs.histogram.LogHistogram`\\ s:

@@ -32,7 +32,7 @@ Merge semantics: the kernel's accumulator realises the total order
 (score desc, global row asc); live rows appear in the flat layout in id
 order (pad rows are dead and never candidates), so global-row order among
 candidates == catalog-id order and any multi-shard/multi-group query is
-bit-identical to the single-shard ``GamRetriever(device=True)`` path — and
+bit-identical to the single-shard ``gam-device`` backend — and
 to ``lax.top_k`` over the dense masked score matrix, which the retained
 ``query_dense_reference`` oracle still computes for parity tests.
 

@@ -32,11 +32,11 @@ from repro.compress import (
     group_varint_encode,
     pattern_dict_decode,
     pattern_dict_encode,
+    pattern_dict_nbytes,
     quantization_error_bound,
     quantize_int8,
 )
 from repro.core.inverted_index import (
-    CompressedInvertedIndex,
     InvertedIndex,
     csr_to_table,
     table_to_csr,
@@ -250,6 +250,58 @@ def test_compressed_exact_path_matches_brute_oracle(backend):
     b = comp.query(users, KAPPA, exact=True)
     np.testing.assert_array_equal(a.ids, b.ids)
     np.testing.assert_allclose(b.scores, a.scores, rtol=1e-5, atol=1e-6)
+
+
+def _catalog_bytes_per_item(retriever):
+    """Serving-state bytes a row of a ``gam-device`` catalog costs: the
+    factors (int8 slab and block scales, or f32 rows), the posting table
+    (varint CSR when compressed) and the pattern bitsets (a dictionary of
+    distinct patterns when compressed)."""
+    meta = retriever._retrieve_meta
+    compressed = retriever.spec.compress_postings
+    if meta.quantize == "int8":
+        factor = np.asarray(meta.factors_q).nbytes + np.asarray(
+            meta.scales).nbytes
+    else:
+        factor = retriever.items.nbytes
+    table = np.asarray(retriever.device_index.table)
+    counts = np.asarray(retriever.device_index.counts)
+    bits = np.asarray(meta.item_bits_t)
+    if compressed:
+        index = encode_postings(*table_to_csr(table, counts)).nbytes
+        rows = np.ascontiguousarray(bits.T[:retriever.n_items])
+        patterns = pattern_dict_nbytes(*pattern_dict_encode(rows))
+    else:
+        index = table.nbytes + counts.nbytes
+        patterns = bits.nbytes
+    return (factor + index + patterns) / retriever.n_items
+
+
+@pytest.mark.parametrize("rerank_factor,compress", [(2, False), (4, False),
+                                                    (4, True)])
+def test_int8_catalog_keeps_exact_path_ids_in_fewer_bytes(rerank_factor,
+                                                          compress):
+    """Each int8 representation answers the exact path with the f32
+    path's ids, in fewer bytes a row; with varint postings and the pattern
+    dictionary, at most a quarter of f32's."""
+    items = unit_factors(240, CFG.k, 7)
+    users = unit_factors(8, CFG.k, 8)
+    tau, mask = _mapped(items)
+    bucket = int(np.bincount(tau[mask].ravel(), minlength=CFG.p).max())
+    f32 = open_retriever(_spec("gam-device", bucket=bucket), items)
+    int8 = open_retriever(
+        _spec("gam-device", bucket=bucket, quantize="int8",
+              rerank_factor=rerank_factor, compress_postings=compress),
+        items)
+    np.testing.assert_array_equal(
+        int8.query(users, KAPPA, exact=True).ids,
+        f32.query(users, KAPPA, exact=True).ids)
+    assert int8.stats()["n_spill"] == f32.stats()["n_spill"] == 0
+    f32_bytes = _catalog_bytes_per_item(f32)
+    int8_bytes = _catalog_bytes_per_item(int8)
+    assert int8_bytes < f32_bytes
+    if compress:
+        assert 4 * int8_bytes <= f32_bytes, (int8_bytes, f32_bytes)
 
 
 @pytest.mark.parametrize("backend", ["gam", "gam-device", "sharded"])

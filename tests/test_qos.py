@@ -438,6 +438,71 @@ def test_multihost_serves_around_faults_bit_identically():
     assert svc.metrics.n_failovers > 0
 
 
+@pytest.mark.parametrize("qos_on", [True, False], ids=["qos", "no_qos"])
+def test_overload_stream_under_faults_is_typed_and_never_wrong(qos_on):
+    """Twelve bursts of 16 requests (10 priority-0, 6 priority-1) against
+    two batches of 4 served a round, on two replicated hosts whose second
+    stalls or runs slow on a seeded share of the rounds.  Every admitted
+    request ends answered or with a typed outcome (none lost), and every
+    answer not flagged degraded is the fault-free ``sharded`` oracle's.
+    With QoS the queue caps and the priority-1 deadline shed the backlog;
+    without it the whole backlog is served."""
+    items = _factors(300, CFG.k, 13)
+    burst, rounds, bs = 16, 12, 4
+    users = _factors(rounds * burst, CFG.k, 14)
+    want = open_retriever(_spec(), items=items).query(users)
+    t, clock = _manual_clock()
+    policy = (QosPolicy(queue_caps=(8, 4), deadlines_s=(60.0, 0.05),
+                        hedge_factor=3.0) if qos_on else None)
+    fi = FaultInjector("stall=0.25,slow=0.25:0.05,hosts=1", seed=0)
+    svc = open_retriever(
+        _spec("sharded-multihost", batch_size=bs, max_delay_s=60.0),
+        items=items, faults=fi, qos=policy, clock=clock)
+    mb = svc.batcher
+    admitted, admission_sheds = [], 0
+    for r in range(rounds):
+        mb.batch_size = len(users) + 1          # the burst lands as a backlog
+        for j in range(burst):
+            row = r * burst + j
+            try:
+                admitted.append((mb.submit(users[row],
+                                           priority=int(j >= 10)), row))
+            except RequestShed as shed:
+                assert shed.reason == "queue_full"
+                admission_sheds += 1
+        mb.batch_size = bs
+        mb.flush()                              # 8 served, 16 arrived
+        mb.flush()
+        t[0] += 0.02
+    while mb.pending:
+        mb.flush()
+
+    kinds = {"exact": 0, "degraded": 0, "shed": 0, "evicted": 0}
+    for rid, row in admitted:
+        got = mb.result(rid)
+        assert got is not None, f"request {rid} lost"
+        if isinstance(got, RequestShed):
+            kinds["shed"] += 1
+        elif isinstance(got, ResultEvicted):
+            kinds["evicted"] += 1
+        elif got.degraded:
+            kinds["degraded"] += 1
+        else:
+            kinds["exact"] += 1
+            np.testing.assert_array_equal(got.ids, want.ids[row])
+            np.testing.assert_array_equal(got.scores, want.scores[row])
+    assert kinds["exact"] > 0
+    assert fi.n_stalls > 0                      # chaos actually happened
+    snap = svc.metrics.snapshot()
+    if qos_on:
+        assert admission_sheds >= 1 and snap["shed_total"] >= 1
+        assert snap["n_failovers"] >= 1
+    else:
+        assert admission_sheds == 0 and snap["shed_total"] == 0
+        assert kinds["exact"] + kinds["degraded"] + kinds["evicted"] \
+            == rounds * burst
+
+
 def test_breaker_auto_marks_down_and_probe_recovers():
     t, clock = _manual_clock()
     items = _factors(300, CFG.k, 0)

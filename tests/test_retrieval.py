@@ -61,40 +61,61 @@ def test_close_factors_overlap_far_factors_conflict():
     assert ov_near > 4 * max(ov_far, 0.5)
 
 
+def _check_overlap_along_great_circle(k, seed):
+    """Overlap of phi(z) with phi at nine angles from z to -z.
+
+    A parse-tree slot encodes a coordinate: a support slot its index and
+    sign, a zero-run slot its index and the last support coordinate before
+    it (with that one's sign), or, before the first support coordinate,
+    its index alone.  So two points share exactly the support coordinates
+    of equal sign, plus some of the coordinates zero in both."""
+    from repro.core.tessellation import ternary_pattern
+    cfg = GamConfig(k=k, scheme="parse_tree")
+    rng = np.random.default_rng(seed)
+    z = rng.normal(size=(k,)).astype(np.float32)
+    z /= np.linalg.norm(z)
+    orth = rng.normal(size=(k,)).astype(np.float32)
+    orth -= (orth @ z) * z
+    orth /= np.linalg.norm(orth)
+    angles = np.linspace(0, np.pi, 9)
+    pts = np.stack([np.cos(a) * z + np.sin(a) * orth for a in angles])
+    tau, _ = sparse_map(jnp.asarray(pts), cfg)
+    tau0, _ = sparse_map(jnp.asarray(z[None]), cfg)
+    ov = np.asarray(pattern_overlap(tau0, tau))
+    # overlap at angle 0 is full; at pi the support signs are mirrored so
+    # only matching zero-runs may still share slots — less than full
+    assert ov[0] == k
+    assert ov[-1] < k
+    # support coordinates (nonzero pattern) never overlap at angle pi
+    p0 = np.asarray(ternary_pattern(jnp.asarray(z[None])))[0]
+    pats = np.asarray(ternary_pattern(jnp.asarray(pts)))
+    ppi = pats[-1]
+    t0, tpi = np.asarray(tau0)[0], np.asarray(tau)[-1]
+    sup_slots0 = set(t0[p0 != 0].tolist())
+    sup_slots_pi = set(tpi[ppi != 0].tolist())
+    assert not (sup_slots0 & sup_slots_pi)
+    # at every angle: the same-sign support coordinates always share their
+    # slot, and past them only coordinates zero in both points can
+    same_sign = ((pats == p0) & (p0 != 0)).sum(axis=1)
+    both_zero = ((pats == 0) & (p0 == 0)).sum(axis=1)
+    assert np.all(ov >= same_sign)
+    assert np.all(ov <= same_sign + both_zero)
+    # at pi every zero run follows a support coordinate of the other sign,
+    # except the leading one: the antipode shares exactly the slots before
+    # z's first support coordinate
+    assert ov[-1] == int(np.argmax(p0 != 0))
+
+
 def test_overlap_decreases_with_angle_property():
     pytest.importorskip("hypothesis")
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
 
     @settings(max_examples=25, deadline=None)
     @given(st.integers(4, 24), st.integers(0, 2**31 - 1))
+    @example(5, 51272)
     def check(k, seed):
-        cfg = GamConfig(k=k, scheme="parse_tree")
-        rng = np.random.default_rng(seed)
-        z = rng.normal(size=(k,)).astype(np.float32)
-        z /= np.linalg.norm(z)
-        orth = rng.normal(size=(k,)).astype(np.float32)
-        orth -= (orth @ z) * z
-        orth /= np.linalg.norm(orth)
-        angles = np.linspace(0, np.pi, 9)
-        pts = np.stack([np.cos(a) * z + np.sin(a) * orth for a in angles])
-        tau, _ = sparse_map(jnp.asarray(pts), cfg)
-        tau0, _ = sparse_map(jnp.asarray(z[None]), cfg)
-        ov = np.asarray(pattern_overlap(tau0, tau))
-        # overlap at angle 0 is full; at pi the support signs are mirrored so
-        # only matching zero-runs may still share slots — less than full
-        assert ov[0] == k
-        assert ov[-1] < k
-        # support coordinates (nonzero pattern) never overlap at angle pi
-        from repro.core.tessellation import ternary_pattern
-        p0 = np.asarray(ternary_pattern(jnp.asarray(z[None])))[0]
-        ppi = np.asarray(ternary_pattern(jnp.asarray(pts[-1:])))[0]
-        t0, tpi = np.asarray(tau0)[0], np.asarray(tau)[-1]
-        sup_slots0 = set(t0[p0 != 0].tolist())
-        sup_slots_pi = set(tpi[ppi != 0].tolist())
-        assert not (sup_slots0 & sup_slots_pi)
-        # loose monotonicity: first half >= second half on average
-        assert ov[:4].mean() >= ov[5:].mean()
+        _check_overlap_along_great_circle(k, seed)
 
     check()
 

@@ -25,6 +25,8 @@ from repro.retriever import (
 )
 
 BACKENDS = ["brute", "gam", "gam-device", "sharded", "sharded-multihost"]
+ITEMS = _factors(200, 16, 0)
+USERS = _factors(8, 16, 1)
 
 
 def _spec(backend, **kw):
@@ -424,3 +426,10 @@ def test_duplicate_ids_rejected_on_build():
         with pytest.raises(ValueError, match="unique"):
             open_retriever(_spec(backend), items=items,
                            ids=np.array([0, 1, 1, 2]))
+
+
+def test_no_warning_from_spec_driven_path(recwarn):
+    open_retriever(RetrieverSpec(cfg=CFG, backend="gam", min_overlap=2),
+                   items=ITEMS).query(USERS, 5)
+    assert not [w for w in recwarn.list
+                if issubclass(w.category, DeprecationWarning)]
